@@ -1,9 +1,11 @@
-"""Kernel micro-benchmarks: wall time per call (interpret mode on CPU — a
-correctness-path timing, NOT TPU performance; TPU perf comes from the
-roofline analysis) plus the analytic per-op latency table the simulator's
-resources implement (Table 2 constants)."""
+"""Kernel micro-benchmarks: wall time per call on the default device —
+compiled on a TPU, interpret mode anywhere else (a correctness-path
+timing, not TPU performance); every row names the platform — plus the
+analytic per-op latency table the simulator's resources implement
+(Table 2 constants)."""
 from __future__ import annotations
 
+import functools
 import time
 from typing import List
 
@@ -28,7 +30,14 @@ def _time(fn, *args, reps=3):
 def kernel_microbench() -> List[str]:
     rng = np.random.default_rng(0)
     rows = []
-    print("\n== kernel microbench (interpret-mode wall time per call)")
+    dev = jax.devices()[0]
+    interpret = dev.platform != "tpu"
+    mode = "interpret" if interpret else "compiled"
+    ops_ = {name: functools.partial(getattr(ops, name), interpret=interpret)
+            for name in ("mws_bitwise", "bitserial_add", "bitserial_mul",
+                         "shift_add_mul", "int8_matmul", "flash_attention")}
+    print(f"\n== kernel microbench ({mode} on {dev.platform} "
+          f"{dev.device_kind}, wall time per call)")
     stack = jnp.asarray(rng.integers(-2**31, 2**31, (8, 64, 512),
                                      dtype=np.int32))
     a = jnp.asarray(rng.integers(-2**20, 2**20, (64, 512), dtype=np.int32))
@@ -37,17 +46,19 @@ def kernel_microbench() -> List[str]:
     b8 = jnp.asarray(rng.integers(-128, 128, (256, 128), dtype=np.int8))
     q = jnp.asarray(rng.normal(size=(4, 128, 64)).astype(np.float32))
     cases = [
-        ("mws_and", lambda: ops.mws_bitwise(stack, "and")),
-        ("bitserial_add", lambda: ops.bitserial_add(a, b)),
-        ("bitserial_mul", lambda: ops.bitserial_mul(a, b)),
-        ("shift_add_mul", lambda: ops.shift_add_mul(a, b)),
-        ("int8_matmul", lambda: ops.int8_matmul(a8, b8)),
-        ("flash_attention", lambda: ops.flash_attention(q, q, q)),
+        ("mws_and", lambda: ops_["mws_bitwise"](stack, "and")),
+        ("bitserial_add", lambda: ops_["bitserial_add"](a, b)),
+        ("bitserial_mul", lambda: ops_["bitserial_mul"](a, b)),
+        ("shift_add_mul", lambda: ops_["shift_add_mul"](a, b)),
+        ("int8_matmul", lambda: ops_["int8_matmul"](a8, b8)),
+        ("flash_attention", lambda: ops_["flash_attention"](q, q, q)),
     ]
     for name, fn in cases:
         us = _time(fn)
         print(f"  {name:16s} {us:10.1f} us/call")
-        rows.append(csv_row(f"kernel/{name}", f"{us:.1f}", "us_per_call"))
+        rows.append(csv_row(f"kernel/{name}", f"{us:.1f}",
+                            f"us_per_call {mode} {dev.platform} "
+                            f"{dev.device_kind}"))
     return rows
 
 

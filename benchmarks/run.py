@@ -18,7 +18,9 @@ the deterministic ``--only`` order regardless of completion order:
 deterministic suite.  (The wall-clock-measuring suites — ``simperf``,
 ``perf`` — print timings, which naturally vary run to run and are skewed
 when siblings saturate the CPU; run those with ``--jobs 1`` when the
-numbers matter.)
+numbers matter.)  Workers run JAX on the CPU only: an accelerator belongs
+to one process, so the suites that run on the device (``DEVICE_SUITES``)
+stay in the parent.
 
 Profiling: ``--profile`` wraps the selected suites in cProfile and prints
 the top-20 cumulative entries afterwards, so perf work starts from data.
@@ -33,12 +35,15 @@ import contextlib
 import functools
 import io
 import json
+import os
 import sys
 import time
 from typing import Dict, List, Optional, Tuple
 
 #: suites whose signature takes a ``smoke`` kwarg (CI-sized shrink)
 SMOKE_AWARE = {"mix", "gc", "gc_policies", "serving", "faults", "fleet"}
+#: suites that run on the default JAX device, which a worker must not take
+DEVICE_SUITES = {"kernels"}
 
 
 def _suite_table() -> Dict:
@@ -90,6 +95,12 @@ def _run_one(name: str, smoke: bool) -> Tuple[str, List[str], str, Optional[str]
         return name, [f"error/{name},{e},"], buf.getvalue(), str(e)
 
 
+def _host_only_worker() -> None:
+    """Pool initializer: the worker's JAX (imported later, by the suites)
+    stays on the CPU and leaves any accelerator to the parent."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+
+
 def run_suites(wanted: List[str], smoke: bool = False, jobs: int = 1,
                profile: bool = False,
                profile_out: Optional[str] = None) -> Tuple[List[str], List[str]]:
@@ -118,9 +129,15 @@ def run_suites(wanted: List[str], smoke: bool = False, jobs: int = 1,
         # spawn, not fork: jax (imported by the workload suites) runs
         # background threads, and forking a threaded process can deadlock
         ctx = multiprocessing.get_context("spawn")
-        with ProcessPoolExecutor(max_workers=jobs, mp_context=ctx) as pool:
-            futures = [pool.submit(_run_one, name, smoke) for name in wanted]
-            results = [f.result() for f in futures]   # wanted order
+        with ProcessPoolExecutor(max_workers=jobs, mp_context=ctx,
+                                 initializer=_host_only_worker) as pool:
+            futures = {name: pool.submit(_run_one, name, smoke)
+                       for name in wanted if name not in DEVICE_SUITES}
+            local = {name: _run_one(name, smoke)
+                     for name in wanted if name in DEVICE_SUITES}
+            results = [local[name] if name in local
+                       else futures[name].result()
+                       for name in wanted]            # wanted order
 
     if profiler is not None:
         profiler.disable()
@@ -226,6 +243,8 @@ def main() -> None:
     args = ap.parse_args()
 
     wanted = (args.only.split(",") if args.only else list(_suite_table()))
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     t0 = time.time()
     csv_rows, failed = run_suites(wanted, smoke=args.smoke, jobs=args.jobs,
                                   profile=args.profile,

@@ -1,4 +1,4 @@
-"""Batched serving of a small model: continuous-batching decode over a
+"""Batched serving of a small model: static-batch decode over a
 synthetic request queue with latency percentiles.
 
     PYTHONPATH=src python examples/serve_batched.py --arch qwen3-4b

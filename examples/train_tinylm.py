@@ -8,9 +8,6 @@ hardware runs.
         --layers 12 --vocab 32000 --steps 300
 """
 import argparse
-import dataclasses
-
-import jax
 
 from repro.launch.train import train
 from repro.models.config import ArchConfig
@@ -36,18 +33,10 @@ def main():
         remat=False, dtype="float32")
     print(f"[tinylm] params ~ {cfg.param_count()/1e6:.1f}M")
 
-    # route through the production training driver with a custom config
-    import repro.launch.train as T
-    import repro.configs as C
-    C._MODULES["tinylm"] = None
-    orig_get = C.get
-    C.get = lambda n: cfg if n == "tinylm" else orig_get(n)
-    try:
-        res = train("tinylm", steps=args.steps, batch=args.batch,
-                    seq=args.seq, ckpt_dir=args.ckpt_dir, ckpt_every=50,
-                    reduced=False, base_lr=3e-3)
-    finally:
-        C.get = orig_get
+    # the production training driver takes a custom config directly
+    res = train(cfg, steps=args.steps, batch=args.batch, seq=args.seq,
+                ckpt_dir=args.ckpt_dir, ckpt_every=50, reduced=False,
+                base_lr=3e-3)
     print(f"[tinylm] loss {res['first_loss']:.3f} -> {res['final_loss']:.3f} "
           f"over {args.steps} steps")
     assert res['final_loss'] < res['first_loss'], "loss must decrease"

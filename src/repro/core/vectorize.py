@@ -23,17 +23,12 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.extend.core import Literal
 
 from repro.core.isa import (OP_TO_CLASS, Location, OpClass, VectorInstr,
                             latency_band)
 from repro.core.mapping import PageTable
 from repro.hw.ssd_spec import DEFAULT_SSD, SSDSpec
-
-# jax moved Literal across versions; resolve robustly.
-try:
-    from jax.extend.core import Literal  # jax >= 0.4.33
-except ImportError:  # pragma: no cover
-    from jax.core import Literal  # type: ignore
 
 # -- primitive -> mnemonic table (the auto-vectorizer's pattern match) -------
 

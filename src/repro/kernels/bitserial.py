@@ -6,6 +6,10 @@ over int tiles in VMEM.  The ripple-carry adder and shift-add multiplier
 below use ONLY the PuD primitive set {AND, OR, XOR, NOT, shift} — the same
 gate-level circuits SIMDRAM synthesizes — so the kernel is a functional
 model of the in-DRAM computation, executed tile-by-tile in VMEM.
+
+The TPU shifts only 32-bit lanes, so narrower tiles are sign-extended to
+int32, run the ``bits``-wide circuit there and are truncated back: the low
+``bits`` of the wide result are the wrapping narrow result.
 """
 from __future__ import annotations
 
@@ -18,8 +22,8 @@ from jax.experimental import pallas as pl
 
 def _add_kernel(a_ref, b_ref, out_ref, *, bits: int):
     """Ripple-carry add via MAJ(=carry)/XOR(=sum) bit-plane circuit."""
-    a = a_ref[...]
-    b = b_ref[...]
+    a = a_ref[...].astype(jnp.int32)
+    b = b_ref[...].astype(jnp.int32)
 
     def body(_, carry):
         a, b = carry
@@ -28,13 +32,14 @@ def _add_kernel(a_ref, b_ref, out_ref, *, bits: int):
         return s, c
 
     s, c = jax.lax.fori_loop(0, bits, body, (a, b))
-    out_ref[...] = s | c             # carry fully propagated after W steps
+    # carry fully propagated through the low ``bits`` after W steps
+    out_ref[...] = (s | c).astype(out_ref.dtype)
 
 
 def _mul_kernel(a_ref, b_ref, out_ref, *, bits: int):
     """Shift-add multiply: W partial products, each AND+add (bit-serial)."""
-    a = a_ref[...]
-    b = b_ref[...]
+    a = a_ref[...].astype(jnp.int32)
+    b = b_ref[...].astype(jnp.int32)
     acc = jnp.zeros_like(a)
 
     def body(i, acc):
@@ -47,7 +52,7 @@ def _mul_kernel(a_ref, b_ref, out_ref, *, bits: int):
         s, c = jax.lax.fori_loop(0, bits * 2, add_body, (acc, pp))
         return s | c
 
-    out_ref[...] = jax.lax.fori_loop(0, bits, body, acc)
+    out_ref[...] = jax.lax.fori_loop(0, bits, body, acc).astype(out_ref.dtype)
 
 
 def _run(kernel, a, b, block_rows, block_cols, interpret):
@@ -65,7 +70,7 @@ def _run(kernel, a, b, block_rows, block_cols, interpret):
 
 
 def bitserial_add(a: jnp.ndarray, b: jnp.ndarray, block_rows: int = 8,
-                  block_cols: int = 512, interpret: bool = True):
+                  block_cols: int = 512, interpret: bool = False):
     """Elementwise a+b via the bit-serial MAJ/XOR adder (int32/int8 tiles)."""
     bits = a.dtype.itemsize * 8
     return _run(functools.partial(_add_kernel, bits=bits), a, b,
@@ -73,7 +78,7 @@ def bitserial_add(a: jnp.ndarray, b: jnp.ndarray, block_rows: int = 8,
 
 
 def bitserial_mul(a: jnp.ndarray, b: jnp.ndarray, block_rows: int = 8,
-                  block_cols: int = 512, interpret: bool = True):
+                  block_cols: int = 512, interpret: bool = False):
     """Elementwise a*b via bit-serial shift-add partial products."""
     bits = a.dtype.itemsize * 8
     return _run(functools.partial(_mul_kernel, bits=bits), a, b,

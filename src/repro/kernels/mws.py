@@ -43,7 +43,7 @@ def _mws_kernel(stack_ref, out_ref, *, op: str, n_ops: int):
 
 def mws_bitwise(stack: jnp.ndarray, op: str = "and",
                 block_rows: int = 8, block_cols: int = 512,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool = False) -> jnp.ndarray:
     """Bulk bitwise reduce over ``stack[n_ops, rows, cols]`` (int dtype).
 
     ``block_rows``/``block_cols`` define the VMEM tile; cols should be a

@@ -19,20 +19,22 @@ from jax.experimental import pallas as pl
 
 
 def _shift_add_kernel(a_ref, b_ref, out_ref, *, bits: int):
-    a = a_ref[...]
-    b = b_ref[...]
+    # 32-bit lanes only shift on the TPU; the truncating cast back wraps
+    a = a_ref[...].astype(jnp.int32)
+    b = b_ref[...].astype(jnp.int32)
     acc = jnp.zeros_like(a)
 
     def round_(i, acc):
         bit = (b >> i) & 1                      # latch-broadcast bit plane
         return acc + jnp.where(bit == 1, a << i, 0)
 
-    out_ref[...] = jax.lax.fori_loop(0, bits, round_, acc)
+    out_ref[...] = jax.lax.fori_loop(0, bits, round_, acc).astype(
+        out_ref.dtype)
 
 
 def shift_add_mul(a: jnp.ndarray, b: jnp.ndarray, bits: int = 8,
                   block_rows: int = 8, block_cols: int = 512,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool = False) -> jnp.ndarray:
     """a * (b & ((1<<bits)-1)) via the Ares-Flash shift-and-add datapath."""
     rows, cols = a.shape
     block_rows = min(block_rows, rows)

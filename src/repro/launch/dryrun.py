@@ -1,12 +1,18 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# 512 fake CPU devices stand in for the production meshes: pin the CPU
+# platform (a TPU host would otherwise hand JAX its chips) and add the
+# device count to any XLA_FLAGS the caller set
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512"]))
 
 """Multi-pod dry-run: lower + compile every (architecture x input shape)
 cell on the production meshes and extract the roofline terms.
 
-The two lines above MUST run before any other import (jax locks the device
-count at first init); do not set this flag globally — smoke tests and
-benches see 1 device.
+The lines above MUST run before any other import (jax locks the platform
+and device count at first init); do not set them globally — smoke tests
+and benches see 1 device.  Exits non-zero when any cell fails.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch tinyllama-1.1b \
@@ -16,7 +22,7 @@ Usage:
 
 import argparse        # noqa: E402
 import json            # noqa: E402
-import re              # noqa: E402
+import sys             # noqa: E402
 import time            # noqa: E402
 import traceback       # noqa: E402
 
@@ -167,6 +173,9 @@ def main() -> None:
         with open(args.out, "w") as f:
             json.dump(records, f, indent=1)
         print(f"wrote {args.out} ({len(records)} records)")
+    failed = [r for r in records if "error" in r]
+    if failed:
+        sys.exit(f"dryrun: {len(failed)} of {len(records)} cells failed")
 
 
 if __name__ == "__main__":

@@ -8,12 +8,20 @@ data-parallel gradient reduction over the inter-pod links.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
+
+
+def make_mesh(shape, axes, devices=None):
+    """Mesh whose axes GSPMD shards automatically: the model code gives
+    sharding hints, not explicit per-op shardings."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_axes(mesh) -> tuple:

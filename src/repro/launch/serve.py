@@ -1,13 +1,18 @@
-"""Batched serving driver: continuous-batching decode over a request queue.
+"""Batched serving driver: static-batch decode over a request queue.
 
-Serves a (reduced-config) model: requests arrive with prompts of varying
-length; the server left-pads to a batch, prefills once, then decodes the
-whole batch step-by-step, retiring requests at EOS/max-tokens and backfilling
-free slots from the queue.  Reports throughput and per-request latency
-percentiles (the serving analogue of the paper's Fig. 8 tail-latency study).
+Serves a model (reduced config by default, published widths with
+``--full``): requests carry fixed-length prompts; the server takes up to
+``batch`` of them, prefills once, then decodes the whole batch step by
+step until every request has ``max_new`` tokens, and takes the next batch.
+Both step programs are compiled before the timed window opens
+(``compile_s``), so throughput and per-request latency percentiles (the
+serving analogue of the paper's Fig. 8 tail-latency study) hold no
+compilation.
 
   PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
       --requests 16 --batch 4 --max-new 16
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b --full \
+      --requests 16 --batch 8 --prompt-len 1024 --max-new 128
 """
 from __future__ import annotations
 
@@ -20,6 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch.cache import use_compile_cache
+from repro.launch.specs import cache_shapes
 from repro.launch.steps import build_prefill_step, build_serve_step
 from repro.models import model as M
 from repro.sim.stats import percentile
@@ -31,7 +38,7 @@ class Request:
         self.prompt = prompt
         self.max_new = max_new
         self.generated: List[int] = []
-        self.t_arrive = time.time()
+        self.t_arrive = time.perf_counter()
         self.t_done: Optional[float] = None
 
 
@@ -44,29 +51,41 @@ def serve(arch: str, n_requests: int, batch: int, prompt_len: int,
     params = M.init_params(cfg, jax.random.PRNGKey(seed))
     max_seq = prompt_len + max_new
 
-    prefill_fn = jax.jit(build_prefill_step(cfg))
-    serve_fn = jax.jit(build_serve_step(cfg), static_argnames=())
+    # compile both step shapes up front; caches are donated (updated in place)
+    t_compile = time.perf_counter()
+    caches_in = cache_shapes(cfg, batch, max_seq)
+    prefill_fn = jax.jit(build_prefill_step(cfg), donate_argnums=(1,)).lower(
+        params, caches_in,
+        {"tokens": jax.ShapeDtypeStruct((batch, prompt_len), jnp.int32)}
+    ).compile()
+    serve_fn = jax.jit(build_serve_step(cfg), donate_argnums=(1,)).lower(
+        params, caches_in, jax.ShapeDtypeStruct((batch,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32)).compile()
+    compile_s = time.perf_counter() - t_compile
 
     queue = [Request(i, rng.integers(0, cfg.vocab, size=prompt_len,
                                      dtype=np.int32), max_new)
              for i in range(n_requests)]
     done: List[Request] = []
-    t0 = time.time()
+    t0 = time.perf_counter()
     total_tokens = 0
 
     while queue:
         active = [queue.pop(0) for _ in range(min(batch, len(queue)))]
-        tokens = jnp.asarray(np.stack([r.prompt for r in active]))
-        caches = M.init_cache(cfg, len(active), max_seq)
-        logits, caches = prefill_fn(params, caches, {"tokens": tokens})
+        prompts = np.zeros((batch, prompt_len), np.int32)  # idle slots pad
+        prompts[:len(active)] = np.stack([r.prompt for r in active])
+        caches = M.init_cache(cfg, batch, max_seq)
+        logits, caches = prefill_fn(params, caches,
+                                    {"tokens": jnp.asarray(prompts)})
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         for step in range(max_new):
-            for r, tok in zip(active, np.asarray(nxt)):
+            toks = np.asarray(nxt)       # waits for the step on the device
+            for r, tok in zip(active, toks):
                 if r.t_done is None:
                     r.generated.append(int(tok))
                     total_tokens += 1
                     if len(r.generated) >= r.max_new:
-                        r.t_done = time.time()
+                        r.t_done = time.perf_counter()
             if all(r.t_done is not None for r in active):
                 break
             logits, caches = serve_fn(params, caches, nxt,
@@ -74,20 +93,23 @@ def serve(arch: str, n_requests: int, batch: int, prompt_len: int,
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         for r in active:
             if r.t_done is None:
-                r.t_done = time.time()
+                r.t_done = time.perf_counter()
             done.append(r)
 
-    wall = time.time() - t0
+    wall = time.perf_counter() - t0     # every step was waited for above
     lat = [(r.t_done - r.t_arrive) * 1e3 for r in done]
-    out = {
+    leaves = jax.tree_util.tree_leaves(params)
+    return {
         "requests": len(done),
         "tokens": total_tokens,
+        "params": sum(int(x.size) for x in leaves),
+        "param_bytes": sum(int(x.nbytes) for x in leaves),
+        "compile_s": compile_s,
         "tokens_per_s": total_tokens / wall,
         "wall_s": wall,
         "latency_ms_p50": percentile(lat, 50),
         "latency_ms_p99": percentile(lat, 99),
     }
-    return out
 
 
 def main() -> None:
@@ -100,6 +122,7 @@ def main() -> None:
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
     res = serve(args.arch, args.requests, args.batch, args.prompt_len,
                 args.max_new, reduced=not args.full)
     for k, v in res.items():

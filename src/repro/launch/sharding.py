@@ -107,6 +107,17 @@ def param_specs(cfg: ArchConfig, params_shapes: Any, mesh) -> Any:
         params_shapes)
 
 
+def opt_specs(opt_shapes: Any, mesh) -> Any:
+    """AdamW moments follow their parameter's rule; the step is
+    replicated."""
+    data, model = mesh_axes(mesh)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: param_spec_for(path, leaf.shape, mesh, data,
+                                          model)
+        if leaf.ndim > 0 else P(),
+        opt_shapes)
+
+
 def cache_spec_for(path, shape, mesh, data, model) -> P:
     name = _leaf_name(path)
     nd = len(shape)

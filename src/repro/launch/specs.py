@@ -17,7 +17,6 @@ from jax.sharding import PartitionSpec as P
 from repro import configs
 from repro.configs.shapes import SHAPES, ShapeSpec, applicable
 from repro.launch import sharding as SH
-from repro.launch.mesh import mesh_axes
 from repro.launch.steps import (build_prefill_step, build_serve_step,
                                 build_train_step, extra_inputs)
 from repro.models import model as M
@@ -54,8 +53,6 @@ def input_specs(arch: str, shape: str, mesh) -> Tuple[Callable, Tuple, str]:
     ok, reason = applicable(cfg, shape)
     if not ok:
         raise ValueError(reason)
-    data, model = mesh_axes(mesh)
-
     p_shapes = params_shapes(cfg)
     p_specs = SH.param_specs(cfg, p_shapes, mesh)
     params_sds = _sharded(p_shapes, p_specs, mesh)
@@ -83,12 +80,7 @@ def input_specs(arch: str, shape: str, mesh) -> Tuple[Callable, Tuple, str]:
 
     if spec.kind == "train":
         o_shapes = opt_shapes(cfg, p_shapes)
-        o_specs = jax.tree_util.tree_map_with_path(
-            lambda path, leaf: SH.param_spec_for(
-                path, leaf.shape, mesh, data, model)
-            if leaf.ndim > 0 else P(),
-            o_shapes)
-        opt_sds = _sharded(o_shapes, o_specs, mesh)
+        opt_sds = _sharded(o_shapes, SH.opt_specs(o_shapes, mesh), mesh)
         batch = {"tokens": tok_sds((b, s)), "labels": tok_sds((b, s))}
         batch.update(extras_sds())
         fn = build_train_step(cfg)

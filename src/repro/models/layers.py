@@ -45,11 +45,11 @@ def model_axis() -> Optional[str]:
 
 
 def _maybe_shard(x, spec):
-    """Sharding hint that is a no-op outside a mesh context."""
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except (ValueError, RuntimeError, KeyError, TypeError):
+    """Sharding hint, applied only under an active mesh (``jax.set_mesh``).
+    Outside one it is a no-op; under one, a bad spec raises."""
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def shard_tokens(x):
@@ -75,8 +75,11 @@ def shard_model_last(x):
 
 def dense_init(key, d_in, d_out, dtype, scale=None):
     scale = scale if scale is not None else (1.0 / np.sqrt(d_in))
-    return (jax.random.normal(key, (d_in, d_out), jnp.float32)
-            * scale).astype(dtype)
+    # the barrier keeps XLA from folding ``scale`` into the sampler's own
+    # constants, which would move some values by an ulp under jit
+    w = jax.lax.optimization_barrier(
+        jax.random.normal(key, (d_in, d_out), jnp.float32))
+    return (w * scale).astype(dtype)
 
 
 def rmsnorm_init(d, dtype):
@@ -176,10 +179,9 @@ def _sdpa(q, k, v, causal: bool, q_offset=0):
     """q [B,Sq,H,dh], k/v [B,Sk,H,dh] -> [B,Sq,H,dh]; fp32 softmax.
 
     Long sequences are processed in q-row blocks (scan) so the [Sq, Sk]
-    score matrix never materializes — O(Sq/C) blocks of [B,H,C,Sk].  (On
-    real TPU the repro.kernels.attention Pallas kernel replaces this path;
-    the chunked form keeps the CPU dry-run/interpret path identical in
-    FLOPs and memory-bounded.)
+    score matrix never materializes — O(Sq/C) blocks of [B,H,C,Sk].  This
+    is the attention of every backend, the TPU included: the Pallas
+    ``repro.kernels.attention`` kernel is not on the model path.
     """
     b, sq, h, dh = q.shape
     if sq <= SDPA_CHUNK or sq % SDPA_CHUNK != 0:
@@ -439,27 +441,22 @@ def _moe_dispatch_local(cfg: ArchConfig, capacity_factor: float,
 
 def _moe_routed_sharded(p, cfg, xf, top_idx, probs,
                         capacity_factor) -> Optional[jnp.ndarray]:
-    """shard_map expert-parallel path; None if inapplicable (no mesh /
-    non-divisible experts) — caller falls back to the dense path."""
+    """shard_map expert-parallel path; None if inapplicable (no active
+    mesh with the model axis, or experts / tokens that do not divide over
+    it) — the caller then takes the dense path."""
     model_ax = model_axis()
     da = data_axes()
     if not model_ax or not da:
         return None
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or model_ax not in mesh.shape:
-            return None
-        msize = mesh.shape[model_ax]
-        dsize = 1
-        for a in da:
-            dsize *= mesh.shape[a]
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if model_ax not in mesh.shape:
         return None
-    if cfg.n_experts % msize or xf.shape[0] % max(1, dsize):
+    msize = mesh.shape[model_ax]
+    dsize = int(np.prod([mesh.shape[a] for a in da]))
+    if cfg.n_experts % msize or xf.shape[0] % dsize:
         return None
-    from jax.experimental.shard_map import shard_map
     body = _moe_dispatch_local(cfg, capacity_factor, model_ax)
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(da, None), P(da, None), P(da, None),
                   P(model_ax, None, None), P(model_ax, None, None),

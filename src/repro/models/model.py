@@ -73,7 +73,12 @@ def _block_init(kind: str, key, cfg: ArchConfig, dtype) -> Params:
     raise ValueError(kind)
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ArchConfig, key) -> Params:
+    """Random weights from ``key``, as one jitted program: each leaf's
+    float32 draw is scaled, cast and freed inside it, so at most one draw is
+    resident beside the finished ``cfg.dtype`` params (op-by-op dispatch
+    held a draw and its scaled copy)."""
     dtype = jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, 8)
     p: Params = {

@@ -70,3 +70,43 @@ def test_committed_perf_artifact_records_speedup():
     # the serving suite (PR 4) is tracked from its introduction: current
     # only — it has no pre-fast-path baseline to speed up against
     assert data["current"]["serving_events_per_sec"] > 0
+
+
+def test_pool_workers_run_jax_on_the_cpu(monkeypatch):
+    """A worker inherits the parent's environment, then pins its JAX to
+    the CPU before any suite imports JAX: the accelerator stays free."""
+    import multiprocessing
+    import os
+    from concurrent.futures import ProcessPoolExecutor
+
+    from benchmarks.run import _host_only_worker
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    ctx = multiprocessing.get_context("spawn")
+    with ProcessPoolExecutor(1, mp_context=ctx,
+                             initializer=_host_only_worker) as pool:
+        assert pool.submit(os.getenv, "JAX_PLATFORMS").result() == "cpu"
+
+
+def test_device_suites_stay_in_the_parent(monkeypatch):
+    """With ``--jobs > 1`` the suites in DEVICE_SUITES run in the parent
+    process (which holds the device); the rest go to workers.  The probe
+    suite exists only in the parent's table, so a worker would fail it."""
+    import os
+
+    from benchmarks import run
+
+    ran_in = []
+
+    def probe():
+        ran_in.append(os.getpid())
+        return ["probe/ok,1,"]
+
+    table = run._suite_table()
+    monkeypatch.setattr(run, "_suite_table", lambda: dict(table, probe=probe))
+    monkeypatch.setattr(run, "DEVICE_SUITES", {"probe"})
+    rows, failed = run.run_suites(["latmodel", "probe"], jobs=2)
+    assert failed == []
+    assert ran_in == [os.getpid()]
+    assert "probe/ok,1," in rows
+    assert any(r.startswith("latmodel/") for r in rows)

@@ -1,4 +1,8 @@
-"""Per-kernel allclose sweeps against the pure-jnp oracles (interpret mode)."""
+"""Per-kernel allclose sweeps against the pure-jnp oracles.
+
+The CPU has no Mosaic compiler, so every call asks for interpret mode
+explicitly; ``tests/test_tpu_compile.py`` compiles the same kernels for a
+described TPU."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -22,7 +26,7 @@ def _rand(shape, dtype):
 @pytest.mark.parametrize("op", ["and", "or", "xor", "nand", "nor"])
 def test_mws_sweep(n_ops, op):
     stack = _rand((n_ops, 16, 256), np.int32)
-    got = ops.mws_bitwise(stack, op)
+    got = ops.mws_bitwise(stack, op, interpret=True)
     want = ref.ref_mws(stack, op)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -32,7 +36,7 @@ def test_mws_sweep(n_ops, op):
 def test_bitserial_add_sweep(shape, dtype):
     a, b = _rand(shape, dtype), _rand(shape, dtype)
     np.testing.assert_array_equal(
-        np.asarray(ops.bitserial_add(a, b)),
+        np.asarray(ops.bitserial_add(a, b, interpret=True)),
         np.asarray(ref.ref_bitserial_add(a, b)))
 
 
@@ -41,7 +45,7 @@ def test_bitserial_add_sweep(shape, dtype):
 def test_bitserial_mul_sweep(shape, dtype):
     a, b = _rand(shape, dtype), _rand(shape, dtype)
     np.testing.assert_array_equal(
-        np.asarray(ops.bitserial_mul(a, b)),
+        np.asarray(ops.bitserial_mul(a, b, interpret=True)),
         np.asarray(ref.ref_bitserial_mul(a, b)))
 
 
@@ -50,7 +54,7 @@ def test_bitserial_mul_sweep(shape, dtype):
 def test_shift_add_sweep(bits, shape):
     a, b = _rand(shape, np.int32), _rand(shape, np.int32)
     np.testing.assert_array_equal(
-        np.asarray(ops.shift_add_mul(a, b, bits=bits)),
+        np.asarray(ops.shift_add_mul(a, b, bits=bits, interpret=True)),
         np.asarray(ref.ref_shift_add_mul(a, b, bits)))
 
 
@@ -63,7 +67,7 @@ def test_int8_matmul_sweep(m, k, n):
     a = jnp.asarray(RNG.integers(-128, 128, size=(m, k), dtype=np.int8))
     b = jnp.asarray(RNG.integers(-128, 128, size=(k, n), dtype=np.int8))
     np.testing.assert_array_equal(
-        np.asarray(ops.int8_matmul(a, b)),
+        np.asarray(ops.int8_matmul(a, b, interpret=True)),
         np.asarray(ref.ref_int8_matmul(a, b)))
 
 
@@ -74,7 +78,7 @@ def test_attention_sweep(h, s, d, causal, dtype):
     q = jnp.asarray(RNG.normal(size=(h, s, d)).astype(dtype))
     k = jnp.asarray(RNG.normal(size=(h, s, d)).astype(dtype))
     v = jnp.asarray(RNG.normal(size=(h, s, d)).astype(dtype))
-    got = ops.flash_attention(q, k, v, causal=causal)
+    got = ops.flash_attention(q, k, v, causal=causal, interpret=True)
     want = ref.ref_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=3e-5, rtol=3e-5)
@@ -84,7 +88,7 @@ def test_attention_cross_lengths():
     q = jnp.asarray(RNG.normal(size=(2, 32, 32)).astype(np.float32))
     k = jnp.asarray(RNG.normal(size=(2, 128, 32)).astype(np.float32))
     v = jnp.asarray(RNG.normal(size=(2, 128, 32)).astype(np.float32))
-    got = ops.flash_attention(q, k, v, causal=False)
+    got = ops.flash_attention(q, k, v, causal=False, interpret=True)
     want = ref.ref_attention(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=3e-5, rtol=3e-5)
@@ -99,10 +103,18 @@ def test_search_kernel_sweep(wpr, rows):
     # plant known matches
     stack = stack.at[3, 0:wpr].set(jnp.arange(wpr))
     query = jnp.arange(wpr, dtype=jnp.int32)
-    got = ops.search_pages(stack, query)
+    got = ops.search_pages(stack, query, interpret=True)
     want = ref.ref_search(stack, query)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert bool(np.asarray(want)[3, 0])
+
+
+def test_compiled_mode_is_the_default():
+    """Without ``interpret=True`` a kernel is compiled for the backend; the
+    CPU has no Mosaic compiler, so it fails instead of interpreting."""
+    stack = _rand((2, 8, 128), np.int32)
+    with pytest.raises(Exception, match="(?i)interpret"):
+        ops.mws_bitwise(stack, "and")
 
 
 def test_search_routes_to_ifp():

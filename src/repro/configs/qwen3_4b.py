@@ -1,5 +1,5 @@
 """qwen3-4b [dense]: 36L d_model=2560 32H (GQA kv=8) d_ff=9728
-vocab=151936 — qk_norm, GQA.  [hf:Qwen/Qwen3-8B; hf]"""
+vocab=151936 — qk_norm, GQA.  [hf:Qwen/Qwen3-4B; hf]"""
 from repro.models.config import ArchConfig
 
 CONFIG = ArchConfig(
@@ -7,6 +7,6 @@ CONFIG = ArchConfig(
     n_layers=36, d_model=2560, n_heads=32, n_kv_heads=8,
     d_ff=9728, vocab=151936,
     qk_norm=True, d_head=128, rope_theta=1_000_000.0,
-    tie_embeddings=True,
-    source="hf:Qwen/Qwen3-8B; hf",
+    tie_embeddings=True, norm_eps=1e-6,
+    source="hf:Qwen/Qwen3-4B; hf",
 )

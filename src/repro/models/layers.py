@@ -206,7 +206,8 @@ def gqa_attention(p: Params, cfg: ArchConfig, x, positions,
     """GQA self-attention (or cross-attention when kv_source is given).
 
     ``cache``: {"k","v" [B,Smax,Hkv,dh], "index" scalar} — decode appends
-    the new token at ``index`` and attends over the valid prefix.
+    the new token at ``index`` (named ``kv_write``) and attends over the
+    valid prefix.
     """
     b, s, d = x.shape
     dh = cfg.head_dim
@@ -230,8 +231,9 @@ def gqa_attention(p: Params, cfg: ArchConfig, x, positions,
     n_rep = cfg.n_heads // cfg.n_kv_heads
     if cache is not None:
         idx = cache["index"]
-        ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, idx, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, idx, 0, 0))
+        with jax.named_scope("kv_write"):
+            ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, idx, 0, 0))
+            cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, idx, 0, 0))
         new_cache = {"k": ck, "v": cv, "index": idx + s}
         # GQA without materializing repeated K/V (perf iteration D1,
         # EXPERIMENTS.md §Perf): fold the group dim into q instead of
@@ -308,9 +310,11 @@ def mla_attention(p: Params, cfg: ArchConfig, x, positions,
 
     if cache is not None:
         idx = cache["index"]
-        cl = jax.lax.dynamic_update_slice(cache["latent"], latent, (0, idx, 0))
-        cr = jax.lax.dynamic_update_slice(cache["k_rope"],
-                                          k_rope[:, :, 0, :], (0, idx, 0))
+        with jax.named_scope("kv_write"):
+            cl = jax.lax.dynamic_update_slice(cache["latent"], latent,
+                                              (0, idx, 0))
+            cr = jax.lax.dynamic_update_slice(cache["k_rope"],
+                                              k_rope[:, :, 0, :], (0, idx, 0))
         new_cache = {"latent": cl, "k_rope": cr, "index": idx + s}
         latent_all, k_rope_all = cl, cr[:, :, None, :]
         q_base = idx

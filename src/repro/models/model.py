@@ -110,36 +110,50 @@ def init_params(cfg: ArchConfig, key) -> Params:
 # -- per-block apply -------------------------------------------------------------
 
 def _attention(p, cfg, x, positions, cache, pos3):
-    if cfg.mla:
-        return L.mla_attention(p, cfg, x, positions, cache)
-    return L.gqa_attention(p, cfg, x, positions, cache, pos3=pos3)
+    with jax.named_scope("attn"):
+        if cfg.mla:
+            return L.mla_attention(p, cfg, x, positions, cache)
+        return L.gqa_attention(p, cfg, x, positions, cache, pos3=pos3)
+
+
+def _norm(cfg: ArchConfig, x, g):
+    with jax.named_scope("norm"):
+        return L.rmsnorm(x, g, cfg.norm_eps)
 
 
 def block_apply(kind: str, cfg: ArchConfig, p: Params, x, positions,
                 cache=None, pos3=None, enc_out=None):
-    """Returns (x, new_cache)."""
-    if kind in ("attn", "moe", "xdec"):
-        h, new_cache = _attention(p["attn"], cfg,
-                                  L.rmsnorm(x, p["ln1"], cfg.norm_eps),
-                                  positions, cache, pos3)
-        x = x + h
-        if kind == "xdec" and enc_out is not None:
-            h, _ = L.gqa_attention(p["xattn"], cfg,
-                                   L.rmsnorm(x, p["lnx"], cfg.norm_eps),
-                                   positions, None, kv_source=enc_out)
+    """Returns (x, new_cache).  Its ops are named ``block/norm``,
+    ``block/attn`` (``attn/kv_write`` for the cache writes),
+    ``block/mlp`` or ``block/moe``, ``block/mixer`` (the SSM blocks), and
+    ``block`` alone for the residual adds (``repro.models.scopes``)."""
+    with jax.named_scope("block"):
+        if kind in ("attn", "moe", "xdec"):
+            h, new_cache = _attention(p["attn"], cfg,
+                                      _norm(cfg, x, p["ln1"]),
+                                      positions, cache, pos3)
             x = x + h
-        xin = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
-        if kind == "moe":
-            x = x + L.moe_apply(p["moe"], cfg, xin)
-        else:
-            x = x + L.mlp_apply(p["mlp"], xin)
-        return x, new_cache
-    if kind == "mamba":
-        return S.mamba_apply(p, cfg, x, cache)
-    if kind == "mlstm":
-        return S.mlstm_apply(p, cfg, x, cache)
-    if kind == "slstm":
-        return S.slstm_apply(p, cfg, x, cache)
+            if kind == "xdec" and enc_out is not None:
+                xn = _norm(cfg, x, p["lnx"])
+                with jax.named_scope("attn"):
+                    h, _ = L.gqa_attention(p["xattn"], cfg, xn, positions,
+                                           None, kv_source=enc_out)
+                x = x + h
+            xin = _norm(cfg, x, p["ln2"])
+            if kind == "moe":
+                with jax.named_scope("moe"):
+                    h = L.moe_apply(p["moe"], cfg, xin)
+            else:
+                with jax.named_scope("mlp"):
+                    h = L.mlp_apply(p["mlp"], xin)
+            return x + h, new_cache
+        with jax.named_scope("mixer"):
+            if kind == "mamba":
+                return S.mamba_apply(p, cfg, x, cache)
+            if kind == "mlstm":
+                return S.mlstm_apply(p, cfg, x, cache)
+            if kind == "slstm":
+                return S.slstm_apply(p, cfg, x, cache)
     raise ValueError(kind)
 
 
@@ -195,68 +209,77 @@ def _strip_index(cache):
 def forward(cfg: ArchConfig, params: Params, x, positions,
             caches: Optional[List] = None, index=None, pos3=None,
             enc_out=None):
-    """Backbone forward. ``x`` [B,S,D] embeddings; returns (h, new_caches)."""
+    """Backbone forward. ``x`` [B,S,D] embeddings; returns (h, new_caches).
+
+    Each segment runs under the ``layers`` scope: the scan's slicing of the
+    stacked params and caches, and its writes of the new caches, are named
+    there and outside ``block``."""
     new_caches: List[Any] = []
-    shared_count = 0
-    for si, (seg_params, (kind, count)) in enumerate(
+    for si, (seg_params, (kind, _)) in enumerate(
             zip(params["segments"], segments_of(cfg))):
         seg_cache = caches[si] if caches is not None else None
-
-        if kind == "sattn":
-            cache_in = None
-            if seg_cache is not None:
-                cache_in = _with_index(jax.tree_util.tree_map(
-                    lambda a: a[0], seg_cache), index)
-            x, nc = block_apply("attn", cfg, params["shared_attn"], x,
-                                positions, cache_in, pos3, enc_out)
-            if seg_cache is not None:
-                nc = _strip_index(nc)
-                new_caches.append(jax.tree_util.tree_map(
-                    lambda a: a[None], nc))
-            else:
-                new_caches.append(None)
-            shared_count += 1
-            continue
-
-        body_kind = kind
-
-        if seg_cache is None:
-            def run_block(p_l, xh):
-                out, _ = block_apply(body_kind, cfg, p_l, xh, positions,
-                                     None, pos3, enc_out)
-                return out
-            if cfg.remat:
-                run_block = jax.checkpoint(run_block)
-            x, _ = jax.lax.scan(
-                lambda c, p_l: (run_block(p_l, c), None), x, seg_params)
-            new_caches.append(None)
-        else:
-            def body(carry, xs):
-                p_l, c_l = xs
-                out, nc = block_apply(body_kind, cfg, p_l, carry, positions,
-                                      _with_index(c_l, index), pos3, enc_out)
-                return out, _strip_index(nc)
-            x, ncs = jax.lax.scan(body, x, (seg_params, seg_cache))
-            new_caches.append(ncs)
+        with jax.named_scope("layers"):
+            x, nc = _segment(cfg, kind, params, seg_params, x, positions,
+                             seg_cache, index, pos3, enc_out)
+        new_caches.append(nc)
     return x, new_caches
 
 
-def encode(cfg: ArchConfig, params: Params, feats, positions):
-    """Bidirectional encoder over (stubbed) frontend features [B,S,D]."""
-    def body(x, p_l):
-        h, _ = L.gqa_attention(p_l["attn"], cfg,
-                               L.rmsnorm(x, p_l["ln1"], cfg.norm_eps),
-                               positions, None, causal=False)
-        x = x + h
-        x = x + L.mlp_apply(p_l["mlp"],
-                            L.rmsnorm(x, p_l["ln2"], cfg.norm_eps))
+def _segment(cfg: ArchConfig, kind: str, params: Params, seg_params, x,
+             positions, seg_cache, index, pos3, enc_out):
+    """One segment of ``forward``: (x, its new caches or None)."""
+    if kind == "sattn":
+        cache_in = None
+        if seg_cache is not None:
+            cache_in = _with_index(jax.tree_util.tree_map(
+                lambda a: a[0], seg_cache), index)
+        x, nc = block_apply("attn", cfg, params["shared_attn"], x,
+                            positions, cache_in, pos3, enc_out)
+        if seg_cache is None:
+            return x, None
+        return x, jax.tree_util.tree_map(lambda a: a[None], _strip_index(nc))
+
+    if seg_cache is None:
+        def run_block(p_l, xh):
+            out, _ = block_apply(kind, cfg, p_l, xh, positions,
+                                 None, pos3, enc_out)
+            return out
+        if cfg.remat:
+            run_block = jax.checkpoint(run_block)
+        x, _ = jax.lax.scan(
+            lambda c, p_l: (run_block(p_l, c), None), x, seg_params)
         return x, None
-    out, _ = jax.lax.scan(body, feats, params["encoder"])
+
+    def body(carry, xs):
+        p_l, c_l = xs
+        out, nc = block_apply(kind, cfg, p_l, carry, positions,
+                              _with_index(c_l, index), pos3, enc_out)
+        return out, _strip_index(nc)
+    return jax.lax.scan(body, x, (seg_params, seg_cache))
+
+
+def encode(cfg: ArchConfig, params: Params, feats, positions):
+    """Bidirectional encoder over (stubbed) frontend features [B,S,D],
+    named as ``forward``'s layers are."""
+    def body(x, p_l):
+        with jax.named_scope("block"):
+            xn = _norm(cfg, x, p_l["ln1"])
+            with jax.named_scope("attn"):
+                h, _ = L.gqa_attention(p_l["attn"], cfg, xn, positions, None,
+                                       causal=False)
+            x = x + h
+            xn = _norm(cfg, x, p_l["ln2"])
+            with jax.named_scope("mlp"):
+                h = L.mlp_apply(p_l["mlp"], xn)
+            return x + h, None
+    with jax.named_scope("layers"):
+        out, _ = jax.lax.scan(body, feats, params["encoder"])
     return out
 
 
 def embed(cfg: ArchConfig, params: Params, tokens):
-    return jnp.take(params["emb"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        return jnp.take(params["emb"], tokens, axis=0)
 
 
 def logits_of(cfg: ArchConfig, params: Params, h, pad_vocab: bool = False):
@@ -264,17 +287,19 @@ def logits_of(cfg: ArchConfig, params: Params, h, pad_vocab: bool = False):
     (e.g. minicpm's 122753) cannot shard over a 16-way model axis, leaving
     the [B,S,V] fp32 logits replicated along it; padding the output dim to a
     512-multiple makes the largest activation of the training step
-    model-shardable.  Padded columns are -inf so logsumexp is unchanged."""
-    h = L.rmsnorm(h, params["ln_f"], cfg.norm_eps)
-    unemb = params["emb"].T if cfg.tie_embeddings else params["unemb"]
-    pad = (-cfg.vocab) % 512 if pad_vocab else 0
-    if pad:
-        unemb = jnp.pad(unemb, ((0, 0), (0, pad)))
-    logits = h @ unemb
-    if pad:
-        neg = jnp.full((pad,), -1e30, logits.dtype)
-        logits = logits.at[..., cfg.vocab:].set(neg)
-    return logits
+    model-shardable.  Padded columns are -inf so logsumexp is unchanged.
+    Named ``head``, with the final norm."""
+    with jax.named_scope("head"):
+        h = L.rmsnorm(h, params["ln_f"], cfg.norm_eps)
+        unemb = params["emb"].T if cfg.tie_embeddings else params["unemb"]
+        pad = (-cfg.vocab) % 512 if pad_vocab else 0
+        if pad:
+            unemb = jnp.pad(unemb, ((0, 0), (0, pad)))
+        logits = h @ unemb
+        if pad:
+            neg = jnp.full((pad,), -1e30, logits.dtype)
+            logits = logits.at[..., cfg.vocab:].set(neg)
+        return logits
 
 
 # -- task-level functions --------------------------------------------------------

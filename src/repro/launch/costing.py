@@ -24,6 +24,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.launch import sharding as SH
 from repro.launch.mesh import mesh_axes
+from repro.models import layers as L
 from repro.models import model as M
 from repro.models.config import ArchConfig
 
@@ -111,15 +112,24 @@ def _x_sds(cfg: ArchConfig, batch: int, seq: int, mesh):
 
 
 def _cache_sds(cfg: ArchConfig, kind: str, batch: int, seq: int, mesh):
-    shapes = jax.eval_shape(
+    """One layer's cache as ``block_apply`` takes it: GQA ``k``/``v`` as a
+    stack of one layer (``M._with_index``), other caches and states
+    without the layer dim."""
+    layer = jax.eval_shape(
         functools.partial(M._block_cache, kind, cfg, batch, seq))
     data, model = mesh_axes(mesh)
-    # cache_spec_for expects a leading layer dim; strip it back off
-    specs = jax.tree_util.tree_map_with_path(
-        lambda path, leaf: P(*tuple(SH.cache_spec_for(
-            path, (1,) + leaf.shape, mesh, data, model))[1:]),
-        shapes)
-    return SH.to_sds(shapes, specs, mesh)
+    stacked = "k" in layer
+
+    def sds(path, leaf):
+        # cache_spec_for expects a leading layer dim
+        shape = (1,) + leaf.shape
+        spec = tuple(SH.cache_spec_for(path, shape, mesh, data, model,
+                                       L.kv_order(cfg, batch)))
+        if not stacked:
+            shape, spec = shape[1:], spec[1:]
+        return jax.ShapeDtypeStruct(shape, leaf.dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+    return jax.tree_util.tree_map_with_path(sds, layer)
 
 
 def probe_segment(cfg: ArchConfig, kind: str, step_kind: str,

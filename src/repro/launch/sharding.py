@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.launch.mesh import mesh_axes
 from repro.models.config import ArchConfig
+from repro.models.layers import kv_order
 
 # column-parallel leaves (shard last dim over "model", -2 over data/FSDP)
 _COL = {"wq", "wk", "wv", "w1", "w3", "w_uq", "w_uk", "w_uv", "w_q",
@@ -118,14 +119,15 @@ def opt_specs(opt_shapes: Any, mesh) -> Any:
         opt_shapes)
 
 
-def cache_spec_for(path, shape, mesh, data, model) -> P:
+def cache_spec_for(path, shape, mesh, data, model, order: str) -> P:
+    """``order``: one K/V layer's axis order, ``kv_order``."""
     name = _leaf_name(path)
     nd = len(shape)
     dataspec = data if data else None
     spec = [None] * nd
-    if name in ("k", "v"):            # [L, B, S, Hkv, dh]
-        spec[1] = dataspec
-        spec[2] = model               # sequence-sharded cache
+    if name in ("k", "v"):            # [L] + one layer in ``order``
+        spec[1 + order.index("b")] = dataspec
+        spec[1 + order.index("s")] = model    # sequence-sharded cache
     elif name in ("latent", "k_rope"):  # [L, B, S, r]
         spec[1] = dataspec
         spec[2] = model
@@ -142,11 +144,13 @@ def cache_spec_for(path, shape, mesh, data, model) -> P:
     return _fit(mesh, shape, spec)
 
 
-def cache_specs(cfg: ArchConfig, cache_shapes: Any, mesh) -> Any:
+def cache_specs(cfg: ArchConfig, cache_shapes: Any, mesh,
+                batch: int) -> Any:
     data, model = mesh_axes(mesh)
+    order = kv_order(cfg, batch)
     return jax.tree_util.tree_map_with_path(
         lambda path, leaf: cache_spec_for(path, leaf.shape, mesh, data,
-                                          model),
+                                          model, order),
         cache_shapes)
 
 

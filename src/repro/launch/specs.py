@@ -90,7 +90,7 @@ def input_specs(arch: str, shape: str, mesh) -> Tuple[Callable, Tuple, str]:
         n_extra = (extras["extra_embeds"].shape[1]
                    if "extra_embeds" in extras else 0)
         c_shapes = cache_shapes(cfg, b, s + n_extra)
-        c_specs = SH.cache_specs(cfg, c_shapes, mesh)
+        c_specs = SH.cache_specs(cfg, c_shapes, mesh, b)
         caches_sds = _sharded(c_shapes, c_specs, mesh)
         batch = {"tokens": tok_sds((b, s))}
         batch.update(extras_sds())
@@ -99,7 +99,7 @@ def input_specs(arch: str, shape: str, mesh) -> Tuple[Callable, Tuple, str]:
 
     # decode: one new token against a seq_len-deep cache
     c_shapes = cache_shapes(cfg, b, s)
-    c_specs = SH.cache_specs(cfg, c_shapes, mesh)
+    c_specs = SH.cache_specs(cfg, c_shapes, mesh, b)
     caches_sds = _sharded(c_shapes, c_specs, mesh)
     token = tok_sds((b,))
     index = jax.ShapeDtypeStruct((), jnp.int32,

@@ -198,6 +198,34 @@ def _sdpa(q, k, v, causal: bool, q_offset=0):
     return outs.transpose(1, 0, 2, 3, 4).reshape(b, sq, h, dh)
 
 
+def kv_order(cfg: ArchConfig, batch: int) -> str:
+    """Axis order of one layer's K/V cache: ``b`` batch, ``s`` position,
+    ``h`` KV head, ``d`` head dim; the stacked cache puts the layer first.
+
+    The layer scan carries the stacked cache where XLA then leaves it in
+    its buffer on the TPU, so that each step writes its new rows in place
+    (``kv_carried``). That holds only where the chip's default layout of
+    the stack is the one XLA gives the carried stack, and the order decides
+    that: with heads a whole number of 128-lane rows wide, [L, B, Hkv, S,
+    dh] at a batch of 1 with grouped heads, and [L, S, Hkv, B, dh] at 1, 2,
+    4 or a multiple of 8 that does not fill the lanes, as 128 does (compile
+    rehearsals for a v5e, ``tests/test_tpu_compile.py``). Elsewhere the
+    carried stacks are copied in and out of every step and their
+    temporaries double, so the cache keeps [L, B, S, Hkv, dh] and is
+    sliced per layer."""
+    if cfg.head_dim % 128 == 0:
+        if batch == 1 and cfg.n_heads > cfg.n_kv_heads:
+            return "bhsd"
+        if batch in (1, 2, 4) or (batch % 8 == 0 and batch % 128):
+            return "shbd"
+    return "bshd"
+
+
+def kv_carried(cfg: ArchConfig, batch: int) -> bool:
+    """Whether the layer scan carries the K/V stacks (``kv_order``)."""
+    return kv_order(cfg, batch) != "bshd"
+
+
 def gqa_attention(p: Params, cfg: ArchConfig, x, positions,
                   cache: Optional[Dict] = None, pos3=None,
                   causal: bool = True,
@@ -205,9 +233,11 @@ def gqa_attention(p: Params, cfg: ArchConfig, x, positions,
                   kv_positions=None):
     """GQA self-attention (or cross-attention when kv_source is given).
 
-    ``cache``: {"k","v" [B,Smax,Hkv,dh], "index" scalar} — decode appends
-    the new token at ``index`` (named ``kv_write``) and attends over the
-    valid prefix.
+    ``cache``: {"k","v": stacked caches, [L] + one layer in
+    ``kv_order(cfg, B)``; "index", "layer": scalars}. The new K/V rows are
+    written at ``index`` of layer ``layer`` in place (named ``kv_write``);
+    the layer is read whole and attended up to each query's position.
+    Returns (out, {"k","v"}: the updated stacks).
     """
     b, s, d = x.shape
     dh = cfg.head_dim
@@ -230,27 +260,35 @@ def gqa_attention(p: Params, cfg: ArchConfig, x, positions,
 
     n_rep = cfg.n_heads // cfg.n_kv_heads
     if cache is not None:
-        idx = cache["index"]
+        idx, layer = cache["index"], cache["layer"]
+        order = kv_order(cfg, b)
+        perm = tuple("bshd".index(a) for a in order)
+        at = (layer,) + tuple(idx if a == "s" else 0 for a in order)
         with jax.named_scope("kv_write"):
-            ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, idx, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, idx, 0, 0))
-        new_cache = {"k": ck, "v": cv, "index": idx + s}
+            ck = jax.lax.dynamic_update_slice(cache["k"],
+                                              k.transpose(perm)[None], at)
+            cv = jax.lax.dynamic_update_slice(cache["v"],
+                                              v.transpose(perm)[None], at)
+        new_cache = {"k": ck, "v": cv}
+        kl = jax.lax.dynamic_index_in_dim(ck, layer, keepdims=False)
+        vl = jax.lax.dynamic_index_in_dim(cv, layer, keepdims=False)
+        kv = order.replace("s", "k")      # the cache's positions are keys
         # GQA without materializing repeated K/V (perf iteration D1,
         # EXPERIMENTS.md §Perf): fold the group dim into q instead of
         # jnp.repeat-ing the cache n_rep times — the cache is read once.
         qg = q.reshape(b, s, cfg.n_kv_heads, n_rep, dh)
-        smax = ck.shape[1]
+        smax = kl.shape[order.index("s")]
         kpos = jax.lax.broadcasted_iota(jnp.int32, (s, smax), 1)
         qpos = idx + jax.lax.broadcasted_iota(jnp.int32, (s, smax), 0)
         mask = kpos <= qpos          # causal over the filled prefix
         # perf iteration D3: contract the cache in bf16 with fp32
-        # accumulation — upcasting ck/cv with astype would materialize the
-        # whole KV cache in fp32 (2x its bytes) before the einsum.
-        logits = jnp.einsum("bqhrd,bkhd->bhrqk", qg, ck,
+        # accumulation — upcasting kl/vl with astype would materialize the
+        # layer's cache in fp32 (2x its bytes) before the einsum.
+        logits = jnp.einsum(f"bqhrd,{kv}->bhrqk", qg, kl,
                             preferred_element_type=jnp.float32) / np.sqrt(dh)
         logits = jnp.where(mask[None, None, None], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bhrqk,bkhd->bqhrd", probs.astype(x.dtype), cv,
+        out = jnp.einsum(f"bhrqk,{kv}->bqhrd", probs.astype(x.dtype), vl,
                          preferred_element_type=jnp.float32)
         out = out.reshape(b, s, cfg.n_heads, dh).astype(x.dtype)
     else:

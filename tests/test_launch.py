@@ -1,6 +1,7 @@
 """Launch-layer laws on the CPU: the serving driver's accounting, jitted
-init, training on a mesh, the compile-cache location, sharding hints, and
-chip_smoke.py's cache-consistency check at a reduced config."""
+init, training on a mesh, the compile-cache location, sharding hints and
+cache specs, and chip_smoke.py's cache-consistency check at a reduced
+config."""
 import dataclasses
 import sys
 from pathlib import Path
@@ -14,8 +15,10 @@ from jax.sharding import PartitionSpec as P
 
 from repro import configs
 from repro.launch import cache as C
+from repro.launch import sharding as SH
 from repro.launch.mesh import make_mesh
 from repro.launch.serve import serve
+from repro.launch.specs import cache_shapes
 from repro.launch.train import train
 from repro.models import layers as L
 from repro.models import model as M
@@ -91,6 +94,26 @@ def test_sharding_hints_apply_only_under_a_mesh():
     mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     with jax.set_mesh(mesh), pytest.raises(Exception, match="nowhere"):
         jax.jit(lambda y: L._maybe_shard(y, P("nowhere")))(x)
+
+
+@pytest.mark.parametrize("batch", [1, 3, 8])
+def test_cache_specs_follow_the_kv_order(batch):
+    """Every K/V cache order (``L.kv_order`` with 128-wide heads: [B, Hkv,
+    S, dh] at 1, [B, S, Hkv, dh] at 3, [S, Hkv, B, dh] at 8): the data axis
+    on the batch dim, the model axis on the sequence dim, nothing else."""
+    cfg = dataclasses.replace(configs.get("tinyllama-1.1b").reduced(),
+                              d_head=128)
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    shapes = cache_shapes(cfg, batch, 16)
+    specs = SH.cache_specs(cfg, shapes, mesh, batch)
+    order = L.kv_order(cfg, batch)
+    assert order == {1: "bhsd", 3: "bshd", 8: "shbd"}[batch]
+    want = [None] * 5
+    want[1 + order.index("b")] = ("data",)
+    want[1 + order.index("s")] = "model"
+    for name in ("k", "v"):
+        assert shapes[0][name].shape[1 + order.index("b")] == batch
+        assert specs[0][name] == P(*want)
 
 
 def test_cache_consistency_check_passes_and_catches_a_bad_index(

@@ -1,5 +1,7 @@
 """Model-zoo correctness: cache-consistency (prefill+decode == full forward),
 MoE routing laws, shapes/finiteness per family."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,27 +12,37 @@ from repro.models import model as M
 from repro.models.config import ArchConfig
 
 
-def _mk(arch):
+def _mk(arch, d_head=None):
     cfg = configs.get(arch).reduced()
+    if d_head:
+        cfg = dataclasses.replace(cfg, d_head=d_head)
     params = M.init_params(cfg, jax.random.PRNGKey(1))
     return cfg, params
 
 
-# one cheap arch stays in the fast tier; the rest of the cache-consistency
-# grid runs nightly
-@pytest.mark.parametrize("arch", [
-    "tinyllama-1.1b",
-    pytest.param("qwen3-4b", marks=pytest.mark.slow),
-    pytest.param("deepseek-v2-236b", marks=pytest.mark.slow),
-    pytest.param("zamba2-1.2b", marks=pytest.mark.slow),
-    pytest.param("xlstm-125m", marks=pytest.mark.slow),
+# one cheap arch stays in the fast tier: as reduced (16-wide heads, K/V
+# sliced per layer) and with 128-wide heads at a batch of each order the
+# layer scan carries (1, 8) and at one it slices (3), ``L.kv_order``; the
+# rest of the cache-consistency grid runs nightly
+@pytest.mark.parametrize("arch, B, d_head", [
+    pytest.param("tinyllama-1.1b", 2, None, id="tinyllama-1.1b"),
+    pytest.param("tinyllama-1.1b", 1, 128, id="tinyllama-1.1b-batch1"),
+    pytest.param("tinyllama-1.1b", 3, 128, id="tinyllama-1.1b-batch3"),
+    pytest.param("tinyllama-1.1b", 8, 128, id="tinyllama-1.1b-batch8"),
+    pytest.param("qwen3-4b", 2, None, marks=pytest.mark.slow, id="qwen3-4b"),
+    pytest.param("deepseek-v2-236b", 2, None, marks=pytest.mark.slow,
+                 id="deepseek-v2-236b"),
+    pytest.param("zamba2-1.2b", 2, None, marks=pytest.mark.slow,
+                 id="zamba2-1.2b"),
+    pytest.param("xlstm-125m", 2, None, marks=pytest.mark.slow,
+                 id="xlstm-125m"),
 ])
-def test_decode_matches_full_forward(arch):
+def test_decode_matches_full_forward(arch, B, d_head):
     """Prefill(s-1 tokens) + decode(token s-1) must reproduce the logits of
     a full forward over s tokens — validates KV caches, MLA latent caches,
     Mamba/xLSTM recurrent states and position handling."""
-    cfg, params = _mk(arch)
-    B, S = 2, 8
+    cfg, params = _mk(arch, d_head)
+    S = 8
     rng = np.random.default_rng(0)
     tokens = jnp.asarray(rng.integers(0, cfg.vocab, (B, S), dtype=np.int32))
 
@@ -48,6 +60,33 @@ def test_decode_matches_full_forward(arch):
     np.testing.assert_allclose(np.asarray(dec_logits),
                                np.asarray(full_logits),
                                atol=0.15, rtol=0.05)
+
+
+@pytest.mark.parametrize("B", [1, 3, 8])
+def test_prefill_then_decode_steps_match_full_forward(B):
+    """Prefill, then 4 decode steps, each step's logits against a
+    cache-free forward over the same tokens: a row written to the wrong
+    place in the cache shows in a later step, not in the first. 128-wide
+    heads: carried at 1 and 8, sliced at 3 (``L.kv_order``)."""
+    cfg, params = _mk("tinyllama-1.1b", 128)
+    P_, N = 6, 4
+    S = P_ + N
+    rng = np.random.default_rng(1)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab, (B, S), dtype=np.int32))
+    x = M.embed(cfg, params, tokens)
+    pos = jnp.broadcast_to(jnp.arange(S), (B, S))
+    h, _ = M.forward(cfg, params, x, pos)
+    full = M.logits_of(cfg, params, h)[:, P_ - 1:]
+
+    caches = M.init_cache(cfg, B, S + 2)
+    logits, caches = M.prefill(cfg, params, tokens[:, :P_], caches)
+    got = [logits[:, -1]]
+    for i in range(N):
+        logits, caches = M.decode_step(cfg, params, tokens[:, P_ + i],
+                                       P_ + i, caches)
+        got.append(logits)
+    np.testing.assert_allclose(np.asarray(jnp.stack(got, axis=1)),
+                               np.asarray(full), atol=0.15, rtol=0.05)
 
 
 @pytest.mark.slow

@@ -3,8 +3,9 @@
 The TPU compiler ships with libtpu, so the programs the chip runs can be
 compiled here without a chip: every kept Pallas kernel at real sizes (the
 32k-token attention and the int8 cases included), qwen3-4b prefill and
-decode at published widths, and a stablelm-1.6b train step sharded over a
-2x2 mesh.  Nothing executes; these catch what interpret mode cannot —
+decode at published widths, decode steps whose K/V cache stays in place
+(or is sliced where it would not), and a stablelm-1.6b train step sharded
+over a 2x2 mesh.  Nothing executes; these catch what interpret mode cannot —
 Mosaic lowering refusals, VMEM and HBM overruns, unpartitionable programs.
 
 The topology is described inside a module fixture, never at import: only
@@ -12,6 +13,7 @@ one process may load libtpu, and every test worker imports this file.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +27,7 @@ from repro.launch.mesh import make_mesh
 from repro.launch.specs import cache_shapes, opt_shapes, params_shapes
 from repro.launch.steps import build_prefill_step, build_serve_step
 from repro.launch.train import jit_train_step, on_mesh
+from repro.models import layers as L
 
 
 @pytest.fixture(scope="module")
@@ -95,24 +98,106 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _serving_step(step, batch, prompt, max_seq, one_chip, arch="qwen3-4b",
+                  layers=None):
+    """A prefill or decode step at published widths (``layers`` of them
+    where given), caches donated as ``serve()`` and the benchmark donate
+    them: (compiled, the caches' shapes)."""
+    cfg = configs.get(arch)
+    if layers:
+        cfg = dataclasses.replace(cfg, n_layers=layers)
+    shapes = cache_shapes(cfg, batch, max_seq)
+    params, caches = _on(params_shapes(cfg), one_chip), _on(shapes, one_chip)
+    if step == "prefill":
+        fn, args = build_prefill_step(cfg), (
+            params, caches, {"tokens": _sds((batch, prompt), I32, one_chip)})
+    else:
+        fn, args = build_serve_step(cfg), (
+            params, caches, _sds((batch,), I32, one_chip),
+            _sds((), I32, one_chip))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    return compiled, jax.tree_util.tree_leaves(shapes)
+
+
 @pytest.mark.parametrize("step", ["prefill", "decode"])
 def test_qwen3_4b_serving_step_fits_one_v5e(step, one_chip):
     """The serving steps of chip_smoke.py at published widths: batch 8,
     1024-token prompts, a 1152-token cache donated to the step."""
-    cfg = configs.get("qwen3-4b")
-    batch, prompt, max_seq = 8, 1024, 1024 + 128
-    params = _on(params_shapes(cfg), one_chip)
-    caches = _on(cache_shapes(cfg, batch, max_seq), one_chip)
-    if step == "prefill":
-        fn = build_prefill_step(cfg)
-        args = (params, caches,
-                {"tokens": _sds((batch, prompt), I32, one_chip)})
-    else:
-        fn = build_serve_step(cfg)
-        args = (params, caches, _sds((batch,), I32, one_chip),
-                _sds((), I32, one_chip))
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    compiled, _ = _serving_step(step, 8, 1024, 1024 + 128, one_chip)
     assert _device_bytes(compiled) < TPU_V5E.hbm_bytes
+
+
+# the shapes a copy or an asynchronous copy's start produces
+_COPIED = re.compile(r"^\s*(?:ROOT )?%?\S+ = (.*?)[)}] copy(?:-start)?\(",
+                     re.M)
+
+
+def _copied_stacks(compiled, stacks):
+    copied = " ".join(_COPIED.findall(compiled.as_text()))
+    return [a for a in stacks if f"[{','.join(map(str, a.shape))}]" in copied]
+
+
+@pytest.mark.parametrize("batch", [1, 4, 16])
+def test_qwen3_4b_decode_keeps_the_kv_cache_in_place(batch, one_chip):
+    """The benchmark's shapes (max_seq 1280) at the batch of each K/V
+    order (1, 4 and the benchmark's 16): the decode step copies no K/V
+    stack, and its temporaries are under 1% of the stacks (the sliced
+    scan's were above 100%)."""
+    compiled, stacks = _serving_step("decode", batch, 256, 1280, one_chip)
+    assert not _copied_stacks(compiled, stacks)
+    kv_bytes = sum(a.size * a.dtype.itemsize for a in stacks)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.01 * kv_bytes
+
+
+# (arch, layers, batch) where ``L.kv_carried`` holds: grouped and full
+# heads 128 lanes wide, at batch 1 and at multiples of 8 short of 128
+CARRIED = [("qwen2-vl-2b", None, 1), ("qwen2-vl-2b", None, 8),
+           ("dbrx-132b", 2, 16), ("qwen3-4b", 2, 64), ("qwen3-4b", 2, 120),
+           ("llama2-7b", 2, 1), ("llama2-7b", 2, 16)]
+
+
+@pytest.mark.parametrize("arch, layers, batch", CARRIED,
+                         ids=[f"{a}-{b}" for a, _, b in CARRIED])
+def test_decode_keeps_carried_stacks_in_place(arch, layers, batch,
+                                              one_chip):
+    """Other architectures and batches than the benchmark's: the carried
+    K/V stacks are not copied either."""
+    compiled, stacks = _serving_step("decode", batch, 256, 1280, one_chip,
+                                     arch, layers)
+    assert L.kv_carried(configs.get(arch), batch)
+    assert not _copied_stacks(compiled, stacks)
+
+
+# where no order keeps a carried stack in place: 64-wide heads at
+# serve()'s default batch and at 8, a batch with no tile of 8, and one that
+# fills the 128 lanes
+SLICED = [("tinyllama-1.1b", None, 4), ("stablelm-1.6b", None, 8),
+          ("qwen3-4b", None, 12), ("qwen3-4b", 2, 128)]
+
+
+@pytest.mark.parametrize("arch, layers, batch", SLICED,
+                         ids=[f"{a}-{b}" for a, _, b in SLICED])
+def test_decode_slices_where_carried_stacks_are_copied(arch, layers, batch,
+                                                       one_chip, monkeypatch):
+    """The cache is sliced per layer at these shapes because carrying it
+    would copy the stacks: the premise of ``L.kv_order``'s rule."""
+    assert not L.kv_carried(configs.get(arch), batch)
+    monkeypatch.setattr(L, "kv_carried", lambda cfg, b: True)
+    compiled, stacks = _serving_step("decode", batch, 256, 1280, one_chip,
+                                     arch, layers)
+    assert _copied_stacks(compiled, stacks)
+
+
+def test_sliced_stablelm_decode_fits_where_carried_would_not(one_chip,
+                                                            monkeypatch):
+    """stablelm-1.6b at batch 20, max_seq 1280: sliced, the decode step
+    fits one v5e; carried, the copies of its stacks overrun the chip."""
+    compiled, _ = _serving_step("decode", 20, 256, 1280, one_chip,
+                                "stablelm-1.6b")
+    assert _device_bytes(compiled) < TPU_V5E.hbm_bytes
+    monkeypatch.setattr(L, "kv_carried", lambda cfg, b: True)
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED"):
+        _serving_step("decode", 20, 256, 1280, one_chip, "stablelm-1.6b")
 
 
 def test_stablelm_train_step_shards_over_2x2(topo):

@@ -26,6 +26,9 @@ class ArchConfig:
 
     # normalization / attention details
     qk_norm: bool = False             # qwen3-style per-head q/k RMSNorm
+    layernorm: bool = False           # LayerNorm, weight and bias (not RMS)
+    rotary_fraction: float = 1.0      # leading share of each head rotated
+    qkv_bias: bool = False            # bias on the q, k and v projections
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-5
     tie_embeddings: bool = True
@@ -84,13 +87,16 @@ class ArchConfig:
         return True   # all assigned archs decode (seamless is enc-dec)
 
     def param_count(self) -> int:
-        """Approximate parameter count (embeddings + blocks)."""
+        """Parameter count: embeddings, blocks and final norm; exact for
+        attention blocks without MLA, approximate for the others."""
         d, dh = self.d_model, self.head_dim
-        n = self.vocab * d
+        norm = d * (2 if self.layernorm else 1)
+        n = self.vocab * d + norm
         if not self.tie_embeddings:
             n += self.vocab * d
         for blk in self.pattern:
             if blk in ("attn", "moe"):
+                n += 2 * norm
                 if self.mla:
                     n += d * (self.kv_lora_rank + self.rope_head_dim)
                     n += self.kv_lora_rank * self.n_heads * (dh + self.rope_head_dim)
@@ -102,6 +108,10 @@ class ArchConfig:
                 else:
                     n += d * self.n_heads * dh + 2 * d * self.n_kv_heads * dh
                     n += self.n_heads * dh * d
+                    if self.qk_norm:
+                        n += 2 * dh
+                    if self.qkv_bias:
+                        n += (self.n_heads + 2 * self.n_kv_heads) * dh
                 if blk == "moe":
                     ff = self.moe_d_ff or self.d_ff
                     n += self.n_experts * 3 * d * ff

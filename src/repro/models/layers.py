@@ -64,6 +64,18 @@ def shard_tokens(x):
     return x
 
 
+def shard_features(x):
+    """A [rows, features] array's features over every mesh axis, as
+    ``launch/sharding.py`` lays out the embedding table (left as it is
+    where they do not divide the features)."""
+    axes = tuple(a for a in data_axes() + (model_axis(),) if a)
+    mesh = jax.sharding.get_abstract_mesh()
+    if not axes or mesh.empty or \
+            x.shape[-1] % int(np.prod([mesh.shape[a] for a in axes])):
+        return x
+    return jax.lax.with_sharding_constraint(x, P(None, axes))
+
+
 def shard_model_last(x):
     da = data_axes()
     if not da or not model_axis():
@@ -94,15 +106,30 @@ def rmsnorm(x, g, eps=1e-5):
     return (h * jax.lax.rsqrt(var + eps)).astype(x.dtype) * g
 
 
+def layernorm(x, g, b, eps=1e-5):
+    """LayerNorm with weight ``g`` and bias ``b``, normalized in float32
+    as ``rmsnorm`` is."""
+    h = x.astype(jnp.float32)
+    h = h - jnp.mean(h, axis=-1, keepdims=True)
+    var = jnp.mean(h * h, axis=-1, keepdims=True)
+    return (h * jax.lax.rsqrt(var + eps)).astype(x.dtype) * g + b
+
+
 # -- rotary embeddings --------------------------------------------------------
 
 def rope_freqs(head_dim: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
 
 
-def apply_rope(x, positions, theta=10_000.0):
-    """x [..., S, H, dh]; positions [..., S] (int)."""
+def apply_rope(x, positions, theta=10_000.0, fraction=1.0):
+    """x [..., S, H, dh]; positions [..., S] (int). With ``fraction`` < 1
+    only the leading ``int(dh * fraction)`` dims of each head are rotated,
+    at the frequencies of a head that wide; the rest pass through."""
     dh = x.shape[-1]
+    rot = int(dh * fraction)
+    if rot < dh:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], positions, theta), x[..., rot:]], -1)
     freqs = jnp.asarray(rope_freqs(dh, theta), jnp.float32)
     ang = positions[..., None].astype(jnp.float32) * freqs      # [..., S, dh/2]
     cos = jnp.cos(ang)[..., None, :]
@@ -145,6 +172,10 @@ def gqa_init(key, cfg: ArchConfig, dtype) -> Params:
     if cfg.qk_norm:
         p["q_norm"] = rmsnorm_init(dh, dtype)
         p["k_norm"] = rmsnorm_init(dh, dtype)
+    if cfg.qkv_bias:
+        p["bq"] = jnp.zeros((cfg.n_heads * dh,), dtype)
+        p["bk"] = jnp.zeros((cfg.n_kv_heads * dh,), dtype)
+        p["bv"] = jnp.zeros((cfg.n_kv_heads * dh,), dtype)
     return p
 
 
@@ -241,11 +272,17 @@ def gqa_attention(p: Params, cfg: ArchConfig, x, positions,
     """
     b, s, d = x.shape
     dh = cfg.head_dim
-    q = (x @ p["wq"]).reshape(b, s, cfg.n_heads, dh)
+
+    def proj(src, name, heads):
+        y = src @ p["w" + name]
+        if cfg.qkv_bias:
+            y = y + p["b" + name]
+        return y.reshape(b, src.shape[1], heads, dh)
+
+    q = proj(x, "q", cfg.n_heads)
     src = kv_source if kv_source is not None else x
-    sk = src.shape[1]
-    k = (src @ p["wk"]).reshape(b, sk, cfg.n_kv_heads, dh)
-    v = (src @ p["wv"]).reshape(b, sk, cfg.n_kv_heads, dh)
+    k = proj(src, "k", cfg.n_kv_heads)
+    v = proj(src, "v", cfg.n_kv_heads)
     if cfg.qk_norm:
         q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
@@ -254,8 +291,8 @@ def gqa_attention(p: Params, cfg: ArchConfig, x, positions,
             q = apply_mrope(q, pos3, cfg.rope_theta)
             k = apply_mrope(k, pos3, cfg.rope_theta)
         else:
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+            q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_fraction)
+            k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_fraction)
     q = shard_model_last(q.reshape(b, s, -1)).reshape(b, s, cfg.n_heads, dh)
 
     n_rep = cfg.n_heads // cfg.n_kv_heads

@@ -45,24 +45,33 @@ def _attn_init(key, cfg, dtype):
     return L.gqa_init(key, cfg, dtype)
 
 
+def _norm_init(cfg: ArchConfig, name: str, dtype) -> Params:
+    """The norm ``name``: its weight, and with ``cfg.layernorm`` its bias
+    ``<name>_b``."""
+    p = {name: L.rmsnorm_init(cfg.d_model, dtype)}
+    if cfg.layernorm:
+        p[name + "_b"] = jnp.zeros((cfg.d_model,), dtype)
+    return p
+
+
 def _block_init(kind: str, key, cfg: ArchConfig, dtype) -> Params:
     ks = jax.random.split(key, 4)
     if kind == "attn":
-        return {"ln1": L.rmsnorm_init(cfg.d_model, dtype),
+        return {**_norm_init(cfg, "ln1", dtype),
                 "attn": _attn_init(ks[0], cfg, dtype),
-                "ln2": L.rmsnorm_init(cfg.d_model, dtype),
+                **_norm_init(cfg, "ln2", dtype),
                 "mlp": L.mlp_init(ks[1], cfg.d_model, cfg.d_ff, dtype)}
     if kind == "moe":
-        return {"ln1": L.rmsnorm_init(cfg.d_model, dtype),
+        return {**_norm_init(cfg, "ln1", dtype),
                 "attn": _attn_init(ks[0], cfg, dtype),
-                "ln2": L.rmsnorm_init(cfg.d_model, dtype),
+                **_norm_init(cfg, "ln2", dtype),
                 "moe": L.moe_init(ks[1], cfg, dtype)}
     if kind == "xdec":   # encoder-decoder decoder block (self + cross + mlp)
-        return {"ln1": L.rmsnorm_init(cfg.d_model, dtype),
+        return {**_norm_init(cfg, "ln1", dtype),
                 "attn": L.gqa_init(ks[0], cfg, dtype),
-                "lnx": L.rmsnorm_init(cfg.d_model, dtype),
+                **_norm_init(cfg, "lnx", dtype),
                 "xattn": L.gqa_init(ks[1], cfg, dtype),
-                "ln2": L.rmsnorm_init(cfg.d_model, dtype),
+                **_norm_init(cfg, "ln2", dtype),
                 "mlp": L.mlp_init(ks[2], cfg.d_model, cfg.d_ff, dtype)}
     if kind == "mamba":
         return S.mamba_init(key, cfg, dtype)
@@ -83,7 +92,7 @@ def init_params(cfg: ArchConfig, key) -> Params:
     keys = jax.random.split(key, 8)
     p: Params = {
         "emb": L.dense_init(keys[0], cfg.vocab, cfg.d_model, dtype, scale=0.02),
-        "ln_f": L.rmsnorm_init(cfg.d_model, dtype),
+        **_norm_init(cfg, "ln_f", dtype),
         "segments": [],
     }
     if not cfg.tie_embeddings:
@@ -116,9 +125,17 @@ def _attention(p, cfg, x, positions, cache, pos3):
         return L.gqa_attention(p, cfg, x, positions, cache, pos3=pos3)
 
 
-def _norm(cfg: ArchConfig, x, g):
+def _apply_norm(cfg: ArchConfig, x, p: Params, name: str):
+    """The norm ``name`` of ``p``: LayerNorm with its bias where
+    ``cfg.layernorm``, else RMSNorm."""
+    if cfg.layernorm:
+        return L.layernorm(x, p[name], p[name + "_b"], cfg.norm_eps)
+    return L.rmsnorm(x, p[name], cfg.norm_eps)
+
+
+def _norm(cfg: ArchConfig, x, p: Params, name: str):
     with jax.named_scope("norm"):
-        return L.rmsnorm(x, g, cfg.norm_eps)
+        return _apply_norm(cfg, x, p, name)
 
 
 def block_apply(kind: str, cfg: ArchConfig, p: Params, x, positions,
@@ -130,16 +147,16 @@ def block_apply(kind: str, cfg: ArchConfig, p: Params, x, positions,
     with jax.named_scope("block"):
         if kind in ("attn", "moe", "xdec"):
             h, new_cache = _attention(p["attn"], cfg,
-                                      _norm(cfg, x, p["ln1"]),
+                                      _norm(cfg, x, p, "ln1"),
                                       positions, cache, pos3)
             x = x + h
             if kind == "xdec" and enc_out is not None:
-                xn = _norm(cfg, x, p["lnx"])
+                xn = _norm(cfg, x, p, "lnx")
                 with jax.named_scope("attn"):
                     h, _ = L.gqa_attention(p["xattn"], cfg, xn, positions,
                                            None, kv_source=enc_out)
                 x = x + h
-            xin = _norm(cfg, x, p["ln2"])
+            xin = _norm(cfg, x, p, "ln2")
             if kind == "moe":
                 with jax.named_scope("moe"):
                     h = L.moe_apply(p["moe"], cfg, xin)
@@ -294,12 +311,12 @@ def encode(cfg: ArchConfig, params: Params, feats, positions):
     named as ``forward``'s layers are."""
     def body(x, p_l):
         with jax.named_scope("block"):
-            xn = _norm(cfg, x, p_l["ln1"])
+            xn = _norm(cfg, x, p_l, "ln1")
             with jax.named_scope("attn"):
                 h, _ = L.gqa_attention(p_l["attn"], cfg, xn, positions, None,
                                        causal=False)
             x = x + h
-            xn = _norm(cfg, x, p_l["ln2"])
+            xn = _norm(cfg, x, p_l, "ln2")
             with jax.named_scope("mlp"):
                 h = L.mlp_apply(p_l["mlp"], xn)
             return x + h, None
@@ -308,9 +325,31 @@ def encode(cfg: ArchConfig, params: Params, feats, positions):
     return out
 
 
+@jax.custom_vjp
+def _take_rows(table, tokens):
+    return jnp.take(table, tokens, axis=0)
+
+
+def _take_rows_fwd(table, tokens):
+    return jnp.take(table, tokens, axis=0), (table, tokens)
+
+
+def _take_rows_bwd(res, g):
+    """The gather's transpose, its scatter-add summed in float32: a bf16
+    sum over the thousands of positions of a frequent token loses most of
+    their gradient."""
+    table, tokens = res
+    grad = L.shard_features(jnp.zeros(table.shape, jnp.float32))
+    grad = grad.at[tokens].add(g.astype(jnp.float32), mode="drop")
+    return grad.astype(table.dtype), None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
 def embed(cfg: ArchConfig, params: Params, tokens):
     with jax.named_scope("embed"):
-        return jnp.take(params["emb"], tokens, axis=0)
+        return _take_rows(params["emb"], tokens)
 
 
 def logits_of(cfg: ArchConfig, params: Params, h, pad_vocab: bool = False):
@@ -321,7 +360,7 @@ def logits_of(cfg: ArchConfig, params: Params, h, pad_vocab: bool = False):
     model-shardable.  Padded columns are -inf so logsumexp is unchanged.
     Named ``head``, with the final norm."""
     with jax.named_scope("head"):
-        h = L.rmsnorm(h, params["ln_f"], cfg.norm_eps)
+        h = _apply_norm(cfg, h, params, "ln_f")
         unemb = params["emb"].T if cfg.tie_embeddings else params["unemb"]
         pad = (-cfg.vocab) % 512 if pad_vocab else 0
         if pad:

@@ -3,8 +3,9 @@
 The model names its parts with ``jax.named_scope``: ``embed``, ``layers``
 (each segment's scan), ``block`` (one layer), and inside it ``norm``,
 ``attn`` (``kv_write`` around the cache writes), ``mlp`` or ``moe``, and
-``mixer`` (the SSM blocks); ``head`` (final norm and logits).  The names
-reach the optimized HLO as each instruction's ``op_name`` metadata, e.g.
+``mixer`` (the SSM blocks); ``head`` (final norm and logits); and
+``optimizer`` around a train step's AdamW update (``optim/adamw``).  The
+names reach the optimized HLO as each instruction's ``op_name`` metadata:
 ``jit(serve_step)/layers/while/body/closed_call/block/attn/dot_general``,
 and under autodiff wrapped as ``transpose(jvp(block))``.  A profiler trace
 names each device op by its HLO instruction, so ``op_scopes`` turns a
@@ -23,7 +24,7 @@ from typing import Dict, FrozenSet
 
 # disjoint buckets that together take every instruction
 BUCKETS = ("attention", "kv_write", "mlp", "norm_residual", "layer_scan",
-           "head", "unscoped")
+           "head", "optimizer", "unscoped")
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -63,6 +64,8 @@ def bucket(op_name: str) -> str:
         return "layer_scan"
     if s & {"embed", "head"}:
         return "head"
+    if "optimizer" in s:
+        return "optimizer"
     return "unscoped"
 
 

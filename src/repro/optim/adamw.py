@@ -37,30 +37,33 @@ def global_norm(tree) -> jnp.ndarray:
 def adamw_update(params, grads, state: AdamWState, lr,
                  b1: float = 0.9, b2: float = 0.95, eps: float = 1e-8,
                  weight_decay: float = 0.1, clip_norm: float = 1.0):
-    """One AdamW step; returns (new_params, new_state, metrics)."""
-    gnorm = global_norm(grads)
-    scale = jnp.minimum(1.0, clip_norm / (gnorm + 1e-9))
-    step = state.step + 1
-    t = step.astype(jnp.float32)
-    bc1 = 1 - b1 ** t
-    bc2 = 1 - b2 ** t
+    """One AdamW step; returns (new_params, new_state, metrics). Its ops,
+    the clip's global norm included, are named ``optimizer``."""
+    with jax.named_scope("optimizer"):
+        gnorm = global_norm(grads)
+        scale = jnp.minimum(1.0, clip_norm / (gnorm + 1e-9))
+        step = state.step + 1
+        t = step.astype(jnp.float32)
+        bc1 = 1 - b1 ** t
+        bc2 = 1 - b2 ** t
 
-    def upd(p, g, m, v):
-        g = g.astype(jnp.float32) * scale
-        m = b1 * m + (1 - b1) * g
-        v = b2 * v + (1 - b2) * g * g
-        mhat = m / bc1
-        vhat = v / bc2
-        delta = mhat / (jnp.sqrt(vhat) + eps) + weight_decay * p.astype(jnp.float32)
-        return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m, v
+        def upd(p, g, m, v):
+            g = g.astype(jnp.float32) * scale
+            m = b1 * m + (1 - b1) * g
+            v = b2 * v + (1 - b2) * g * g
+            mhat = m / bc1
+            vhat = v / bc2
+            delta = (mhat / (jnp.sqrt(vhat) + eps)
+                     + weight_decay * p.astype(jnp.float32))
+            return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), m, v
 
-    flat_p, tdef = jax.tree_util.tree_flatten(params)
-    flat_g = jax.tree_util.tree_leaves(grads)
-    flat_m = jax.tree_util.tree_leaves(state.mu)
-    flat_v = jax.tree_util.tree_leaves(state.nu)
-    out = [upd(p, g, m, v) for p, g, m, v in
-           zip(flat_p, flat_g, flat_m, flat_v)]
-    new_p = jax.tree_util.tree_unflatten(tdef, [o[0] for o in out])
-    new_m = jax.tree_util.tree_unflatten(tdef, [o[1] for o in out])
-    new_v = jax.tree_util.tree_unflatten(tdef, [o[2] for o in out])
-    return new_p, AdamWState(step, new_m, new_v), {"grad_norm": gnorm}
+        flat_p, tdef = jax.tree_util.tree_flatten(params)
+        flat_g = jax.tree_util.tree_leaves(grads)
+        flat_m = jax.tree_util.tree_leaves(state.mu)
+        flat_v = jax.tree_util.tree_leaves(state.nu)
+        out = [upd(p, g, m, v) for p, g, m, v in
+               zip(flat_p, flat_g, flat_m, flat_v)]
+        new_p = jax.tree_util.tree_unflatten(tdef, [o[0] for o in out])
+        new_m = jax.tree_util.tree_unflatten(tdef, [o[1] for o in out])
+        new_v = jax.tree_util.tree_unflatten(tdef, [o[2] for o in out])
+        return new_p, AdamWState(step, new_m, new_v), {"grad_norm": gnorm}

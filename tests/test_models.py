@@ -188,3 +188,21 @@ def test_mrope_position_streams_matter():
     l1 = M.lm_loss(cfg, params, tokens, labels, extra_embeds=emb, pos3=p1)
     l2 = M.lm_loss(cfg, params, tokens, labels, extra_embeds=emb, pos3=p2)
     assert abs(float(l1) - float(l2)) > 1e-6
+
+
+def test_embedding_gradient_sums_in_float32():
+    """The gather's transpose adds every position's cotangent of a token
+    into its row in float32: here one id fills a seventh of 4096
+    positions, whose bf16 running sum would be about 8% off."""
+    cfg = configs.get("tinyllama-1.1b").reduced()
+    table = (0.02 * jax.random.normal(jax.random.PRNGKey(0), (64, 32))
+             ).astype(jnp.bfloat16)
+    tokens = jnp.zeros((4, 1024), jnp.int32).at[:, ::7].set(3)
+    g = jax.random.normal(jax.random.PRNGKey(1), (4, 1024, 32)
+                          ).astype(jnp.bfloat16)
+    _, vjp = jax.vjp(lambda t: M.embed(cfg, {"emb": t}, tokens), table)
+    got = np.asarray(vjp(g)[0].astype(jnp.float32), np.float64)
+    want = np.zeros((64, 32))
+    np.add.at(want, np.asarray(tokens), np.asarray(g.astype(jnp.float32)))
+    # only the result's rounding to bf16 is left: under 2**-8 relative
+    assert np.linalg.norm(got - want) / np.linalg.norm(want) < 4e-3

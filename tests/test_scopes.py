@@ -160,3 +160,33 @@ def test_other_blocks_are_scoped(arch, pattern):
     m = SC.op_scopes(compiled_text("serve", cfg))
     assert set(m.values()) >= {"attention", "kv_write", "mlp",
                                "norm_residual", "layer_scan", "head"}
+
+
+def test_the_train_steps_adamw_update_lands_in_optimizer():
+    """The AdamW update (and its clip's global norm) is its own bucket;
+    the forward and backward passes keep theirs."""
+    from repro.launch.specs import opt_shapes
+    from repro.launch.steps import build_train_step
+    cfg = _cfg("stablelm-1.6b")
+    params = params_shapes(cfg)
+    batch = {k: jax.ShapeDtypeStruct((BATCH, PROMPT), I32)
+             for k in ("tokens", "labels")}
+    text = jax.jit(build_train_step(cfg), donate_argnums=(0, 1)).lower(
+        params, opt_shapes(cfg, params), batch).compile().as_text()
+    m = SC.op_scopes(text)
+    assert set(m.values()) >= {"attention", "mlp", "norm_residual", "head",
+                               "optimizer"}
+    scoped = re.findall(r"^\s*(?:ROOT )?%?(\S+) = .*op_name=\"([^\"]*)\"",
+                        text, re.M)
+    adam = [n for n, op in scoped if "/optimizer/" in op]
+    assert adam and all(m[n] == "optimizer" for n in adam)
+    # the update's square root of the second moment is the optimizer's
+    assert any(m[n] == "optimizer" for n, op in scoped
+               if op.endswith("/sqrt"))
+
+
+def test_decode_buckets_are_those_of_before_the_optimizer_bucket():
+    m = SC.op_scopes(compiled_text("serve"))
+    assert set(m.values()) == {"attention", "kv_write", "mlp",
+                               "norm_residual", "layer_scan", "head",
+                               "unscoped"}

@@ -4,8 +4,9 @@ The TPU compiler ships with libtpu, so the programs the chip runs can be
 compiled here without a chip: every kept Pallas kernel at real sizes (the
 32k-token attention and the int8 cases included), qwen3-4b prefill and
 decode at published widths, decode steps whose K/V cache stays in place
-(or is sliced where it would not), and a stablelm-1.6b train step sharded
-over a 2x2 mesh.  Nothing executes; these catch what interpret mode cannot —
+(or is sliced where it would not), and stablelm-1.6b train steps sharded
+over a 2x2 mesh, the benchmark's whole 24-layer step at 6 x 4096 among
+them.  Nothing executes; these catch what interpret mode cannot —
 Mosaic lowering refusals, VMEM and HBM overruns, unpartitionable programs.
 
 The topology is described inside a module fixture, never at import: only
@@ -200,24 +201,46 @@ def test_sliced_stablelm_decode_fits_where_carried_would_not(one_chip,
         _serving_step("decode", 20, 256, 1280, one_chip, "stablelm-1.6b")
 
 
-def test_stablelm_train_step_shards_over_2x2(topo):
-    """Two stablelm-1.6b layers at full width, the train step train()
-    jits for a (data=2, model=2) mesh of described chips: each chip holds
-    a quarter of the state."""
-    cfg = dataclasses.replace(configs.get("stablelm-1.6b"), n_layers=2)
+def _train_step(topo, cfg, batch_shape):
+    """The train step train() jits for a (data=2, model=2) mesh of
+    described chips: (compiled, the state's bytes)."""
     mesh = make_mesh((2, 2), ("data", "model"), devices=topo.devices[:4])
-    step_fn, sh, batch_sh = jit_train_step(cfg, 3, 1e-3, mesh, (8, 1024))
+    step_fn, sh, batch_sh = jit_train_step(cfg, 3, 1e-3, mesh, batch_shape)
     p_shapes = params_shapes(cfg)
     o_shapes = opt_shapes(cfg, p_shapes)
     place = lambda shapes, shard: jax.tree_util.tree_map(
         lambda a, s: _sds(a.shape, a.dtype, s), shapes, shard)
-    batch = {k: _sds((8, 1024), I32, batch_sh) for k in ("tokens", "labels")}
+    batch = {k: _sds(batch_shape, I32, batch_sh)
+             for k in ("tokens", "labels")}
     with on_mesh(mesh):
         compiled = step_fn.lower(place(p_shapes, sh["params"]),
                                  place(o_shapes, sh["opt"]), batch).compile()
     state = sum(a.size * a.dtype.itemsize for a in
                 jax.tree_util.tree_leaves((p_shapes, o_shapes)))
+    return compiled, state
+
+
+def test_stablelm_train_step_shards_over_2x2(topo):
+    """Two stablelm-1.6b layers at full width, the train step train()
+    jits for a (data=2, model=2) mesh of described chips: each chip holds
+    a quarter of the state."""
+    cfg = dataclasses.replace(configs.get("stablelm-1.6b"), n_layers=2)
+    compiled, state = _train_step(topo, cfg, (8, 1024))
     args = compiled.memory_analysis().argument_size_in_bytes
     assert abs(args / (state / 4) - 1) < 0.05
     assert _device_bytes(compiled) < TPU_V5E.hbm_bytes
     assert "all-reduce" in compiled.as_text()
+
+
+def test_published_stablelm_train_step_fits_a_2x2_v5e(topo):
+    """The benchmark cell's step (stablelm-1.6b.train-2x2): all 24 layers,
+    6 sequences of 4096, on the (data=2, model=2) mesh. Each chip holds a
+    quarter of the 16.45 GB state, and the step's arguments and
+    temporaries stay under the chip's 16 GB."""
+    cfg = configs.get("stablelm-1.6b")
+    compiled, state = _train_step(topo, cfg, (6, 4096))
+    m = compiled.memory_analysis()
+    assert state == pytest.approx(16.45e9, rel=1e-3)
+    assert abs(m.argument_size_in_bytes / (state / 4) - 1) < 0.05
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes \
+        < TPU_V5E.hbm_bytes

@@ -7,8 +7,18 @@ scores nor the whole K/V of a head are ever resident — VMEM use is fixed by
 the block sizes, not the sequence length.  Causal attention skips the k
 blocks above the diagonal and does not fetch them.
 
-Not on the model path: ``models/layers.py`` runs its own chunked jnp
-attention on every backend.
+``flash_attention`` is forward only and on no model path: it serves the
+kernel tests, ``benchmarks/kernel_bench.py`` and ``chip_smoke.py``.
+
+``flash_mha`` is the model path's (``models/layers._sdpa`` on the TPU): the
+flash attention kernels of ``jax.experimental.pallas.ops.tpu`` (forward, dk/dv
+and dq) under a ``custom_vjp`` of its own. Its forward runs the library's
+kernel with the output left in float32, from which the backward takes its row
+sums ``di = do . o``. The library's own backward takes them from the output
+rounded to q's dtype: then the backward's rows of dS miss summing to zero by
+that rounding, and the miss lands on what the softmax cancels, the gradient
+of a key bias (zero in exact arithmetic), about twice the noise the jnp
+attention leaves there.
 """
 from __future__ import annotations
 
@@ -19,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu import flash_attention as FA
 
 NEG_INF = -1e30
 
@@ -106,3 +117,80 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
+
+
+def _mha_forward(q, k, v, causal: bool, block: int):
+    """The library's forward kernel over q/k/v [B,H,S,dh] in square tiles of
+    ``block``, writing its output in float32: (o, l, m), the softmax's row
+    sums l and maxima m [B,H,S]. Causal grids re-point the k tiles above the
+    diagonal at the next row's first, which is fetched instead."""
+    b, h, s, dh = q.shape
+    scale = 1.0 / np.sqrt(dh)
+    lanes = FA.MIN_BLOCK_SIZE
+
+    def q_map(bi, hi, qi, ki):
+        return bi, hi, qi, 0
+
+    def kv_map(bi, hi, qi, ki):
+        if causal:
+            ki = jax.lax.select(FA.below_or_on_diag(qi, block, ki, block),
+                                ki, 0)
+        return bi, hi, ki, 0
+
+    tile = lambda width, index: pl.BlockSpec((1, 1, block, width), index)
+    stat = jax.ShapeDtypeStruct((b, h, s, lanes), jnp.float32)
+    out_shape = [jax.ShapeDtypeStruct(q.shape, jnp.float32), stat, stat]
+    scratch = [] if block == s else [  # one k tile: no running statistics
+        pltpu.VMEM((1, 1, block, lanes), jnp.float32),
+        pltpu.VMEM((1, 1, block, lanes), jnp.float32),
+        pltpu.VMEM((1, 1, block, dh), jnp.float32)]
+    kernel = functools.partial(
+        FA._flash_attention_kernel, causal=causal, sm_scale=scale,
+        block_k=block, kv_seq_len=s, mask_value=FA.DEFAULT_MASK_VALUE)
+    o, l, m = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0, grid=(b, h, s // block, s // block),
+            in_specs=[tile(dh, q_map), tile(dh, kv_map), tile(dh, kv_map),
+                      None, None, None],
+            out_specs=[tile(dh, q_map), tile(lanes, q_map),
+                       tile(lanes, q_map)],
+            scratch_shapes=scratch),
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "arbitrary")),
+        name=f"flash_mha_fwd_block_{block}",
+        cost_estimate=FA._fwd_cost_estimate(
+            q, k, v, None, None, causal=causal, sm_scale=scale,
+            kernel_inputs_specs=(q, k, v), kernel_outputs_specs=out_shape),
+    )(q, k, v, None, None, None)
+    return o, l[..., 0], m[..., 0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def flash_mha(q, k, v, causal: bool, block: int):
+    """Attention over q/k/v [B,H,S,dh] (sq == sk, ``block`` dividing S) in
+    q's dtype, scaled by 1/sqrt(dh), with a backward."""
+    return _mha_forward(q, k, v, causal, block)[0].astype(q.dtype)
+
+
+def _flash_mha_fwd(q, k, v, causal, block):
+    o, l, m = _mha_forward(q, k, v, causal, block)
+    return o.astype(q.dtype), (q, k, v, o, l, m)
+
+
+def _flash_mha_bwd(causal, block, res, do):
+    q, k, v, o, l, m = res
+    di = jnp.sum(o * do.astype(jnp.float32), axis=-1)
+    common = dict(sm_scale=1.0 / np.sqrt(q.shape[-1]), causal=causal,
+                  mask_value=FA.DEFAULT_MASK_VALUE, debug=False)
+    dk, dv = FA._flash_attention_bwd_dkv(
+        q, k, v, None, None, l, m, do, di, block_q_major=block,
+        block_q=block, block_k_major=block, block_k=block, **common)
+    dq, _ = FA._flash_attention_bwd_dq(
+        q, k, v, None, None, l, m, do, di, block_q_major=block,
+        block_k_major=block, block_k=block, **common)
+    return dq, dk, dv
+
+
+flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)

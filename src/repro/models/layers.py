@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.attention import flash_mha
 from repro.models.config import ArchConfig
 
 Params = Dict[str, Any]
@@ -186,6 +187,11 @@ def _repeat_kv(k, n_rep):
 
 
 SDPA_CHUNK = 512   # q-block size for chunked attention (long sequences)
+# the flash kernel's q and k tiles, forward and backward: the widest that
+# divides the sequence (a v5e at the training cell's shard, [3, 16, 4096,
+# 64] causal, a layer's forward plus gradient: 15.3 ms at 1024, 16.2 at
+# 512, 29.4 at 256, 70.8 at 128; PERF.md section 6)
+FLASH_BLOCKS = (1024, SDPA_CHUNK)
 
 
 def _sdpa_block(q, k, v, causal: bool, q_offset):
@@ -206,23 +212,80 @@ def _sdpa_block(q, k, v, causal: bool, q_offset):
     return out.astype(q.dtype)
 
 
-def _sdpa(q, k, v, causal: bool, q_offset=0):
+def _flash_spec(b: int, h: int):
+    """The flash kernel's q/k/v spec over the active mesh ([B,H,S,dh]):
+    batch over the data axes and heads over the model axis, as
+    ``shard_tokens``/``shard_model_last`` lay q, k and v out. None where
+    they do not divide the batch and the heads."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return P()
+    da, ma = data_axes(), model_axis()
+    dp = int(np.prod([mesh.shape[a] for a in da]))
+    if b % dp or (ma and h % mesh.shape[ma]):
+        return None
+    return P(da or None, ma, None, None)
+
+
+def _flash_sdpa(q, k, v, causal: bool):
+    """``_sdpa`` through the Pallas flash attention with its backward
+    (``kernels.attention.flash_mha``): online softmax over K/V tiles in
+    VMEM, tiles above the diagonal skipped, dq and dk/dv kernels, so no
+    score tile reaches HBM. Tiles: the first of ``FLASH_BLOCKS`` that
+    divides the sequence. A ``pallas_call`` has no partitioning rule:
+    under a mesh it runs in ``shard_map`` on each chip's batch rows and
+    heads. q/k/v [B,S,H,dh], sq == sk."""
+    b, s, h, _ = q.shape
+    block = next(n for n in FLASH_BLOCKS if s % n == 0)
+    attend = lambda q, k, v: flash_mha(q, k, v, causal, block)
+    if not jax.sharding.get_abstract_mesh().empty:
+        spec = _flash_spec(b, h)
+        attend = jax.shard_map(attend, in_specs=(spec,) * 3, out_specs=spec,
+                               check_vma=False)
+    t = lambda x: x.transpose(0, 2, 1, 3)          # [B,S,H,dh] <-> [B,H,S,dh]
+    return t(attend(t(q), t(k), t(v)))
+
+
+def _flash_fits(q, k) -> bool:
+    """The shapes the flash path takes: self-attention lengths (sq == sk)
+    over ``SDPA_CHUNK`` in whole tiles, with batch and heads that divide
+    over the mesh."""
+    b, sq, h, _ = q.shape
+    return (sq == k.shape[1] and sq > SDPA_CHUNK
+            and sq % FLASH_BLOCKS[-1] == 0
+            and _flash_spec(b, h) is not None)
+
+
+def _sdpa(q, k, v, causal: bool):
     """q [B,Sq,H,dh], k/v [B,Sk,H,dh] -> [B,Sq,H,dh]; fp32 softmax.
 
-    Long sequences are processed in q-row blocks (scan) so the [Sq, Sk]
-    score matrix never materializes — O(Sq/C) blocks of [B,H,C,Sk].  This
-    is the attention of every backend, the TPU included: the Pallas
-    ``repro.kernels.attention`` kernel is not on the model path.
+    Programs lowered for the TPU run long self-attention (``_flash_fits``)
+    through the Pallas flash kernel with its backward (``_flash_sdpa``);
+    ``jax.lax.platform_dependent`` keeps that branch alone there and the
+    jnp one alone elsewhere. The jnp attention (``_sdpa_jnp``) runs every
+    shape on other backends and the shapes the kernel does not take on
+    the TPU.
     """
+    if _flash_fits(q, k):
+        return jax.lax.platform_dependent(
+            q, k, v, tpu=lambda q, k, v: _flash_sdpa(q, k, v, causal),
+            default=lambda q, k, v: _sdpa_jnp(q, k, v, causal))
+    return _sdpa_jnp(q, k, v, causal)
+
+
+def _sdpa_jnp(q, k, v, causal: bool):
+    """``_sdpa`` in jnp. Long sequences are processed in q-row blocks
+    (scan) so the [Sq, Sk] score matrix never materializes — O(Sq/C)
+    blocks of [B,H,C,Sk]."""
     b, sq, h, dh = q.shape
     if sq <= SDPA_CHUNK or sq % SDPA_CHUNK != 0:
-        return _sdpa_block(q, k, v, causal, q_offset)
+        return _sdpa_block(q, k, v, causal, 0)
     nblk = sq // SDPA_CHUNK
     qb = q.reshape(b, nblk, SDPA_CHUNK, h, dh).transpose(1, 0, 2, 3, 4)
 
     def blk(carry, inp):
         i, qq = inp
-        out = _sdpa_block(qq, k, v, causal, q_offset + i * SDPA_CHUNK)
+        out = _sdpa_block(qq, k, v, causal, i * SDPA_CHUNK)
         return carry, out
 
     _, outs = jax.lax.scan(blk, (), (jnp.arange(nblk), qb))

@@ -6,8 +6,9 @@ compiled here without a chip: every kept Pallas kernel at real sizes (the
 decode at published widths, decode steps whose K/V cache stays in place
 (or is sliced where it would not), and stablelm-1.6b train steps sharded
 over a 2x2 mesh, the benchmark's whole 24-layer step at 6 x 4096 among
-them.  Nothing executes; these catch what interpret mode cannot —
-Mosaic lowering refusals, VMEM and HBM overruns, unpartitionable programs.
+them, its attention the Pallas flash kernel.  Nothing executes; these
+catch what interpret mode cannot — Mosaic lowering refusals, VMEM and HBM
+overruns, unpartitionable programs.
 
 The topology is described inside a module fixture, never at import: only
 one process may load libtpu, and every test worker imports this file.
@@ -29,6 +30,7 @@ from repro.launch.specs import cache_shapes, opt_shapes, params_shapes
 from repro.launch.steps import build_prefill_step, build_serve_step
 from repro.launch.train import jit_train_step, on_mesh
 from repro.models import layers as L
+from repro.models.scopes import op_scopes
 
 
 @pytest.fixture(scope="module")
@@ -232,15 +234,42 @@ def test_stablelm_train_step_shards_over_2x2(topo):
     assert "all-reduce" in compiled.as_text()
 
 
-def test_published_stablelm_train_step_fits_a_2x2_v5e(topo):
+@pytest.fixture(scope="module")
+def published_train_step(topo):
     """The benchmark cell's step (stablelm-1.6b.train-2x2): all 24 layers,
-    6 sequences of 4096, on the (data=2, model=2) mesh. Each chip holds a
-    quarter of the 16.45 GB state, and the step's arguments and
-    temporaries stay under the chip's 16 GB."""
-    cfg = configs.get("stablelm-1.6b")
-    compiled, state = _train_step(topo, cfg, (6, 4096))
+    6 sequences of 4096, on the (data=2, model=2) mesh."""
+    return _train_step(topo, configs.get("stablelm-1.6b"), (6, 4096))
+
+
+def test_published_stablelm_train_step_fits_a_2x2_v5e(published_train_step):
+    """Each chip holds a quarter of the 16.45 GB state, and the step's
+    arguments and temporaries stay under the chip's 16 GB."""
+    compiled, state = published_train_step
     m = compiled.memory_analysis()
     assert state == pytest.approx(16.45e9, rel=1e-3)
     assert abs(m.argument_size_in_bytes / (state / 4) - 1) < 0.05
     assert m.argument_size_in_bytes + m.temp_size_in_bytes \
         < TPU_V5E.hbm_bytes
+
+
+_TPU_CUSTOM_CALL = re.compile(
+    r'^\s*(?:ROOT )?%?([\w.\-]+) = .*custom_call_target="tpu_custom_call"',
+    re.M)
+
+
+def test_published_stablelm_train_step_runs_flash_attention(
+        published_train_step):
+    """The step's attention is the Pallas flash kernel with its backward:
+    four kernels (the forward, the remat forward that keeps the softmax
+    statistics, the dk/dv and the dq kernels), each of them bucketed as
+    ``attention`` by ``op_scopes``. With no [512, 4096] float32 score
+    block kept, arguments plus temporaries fall from the q-block scan's
+    13.89 GB a chip (10.50 GB with the kernel)."""
+    compiled, _ = published_train_step
+    text = compiled.as_text()
+    calls = _TPU_CUSTOM_CALL.findall(text)
+    assert len(calls) == 4
+    buckets = op_scopes(text)
+    assert {buckets[c] for c in calls} == {"attention"}
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 12e9

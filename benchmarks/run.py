@@ -1,5 +1,5 @@
 """Benchmark entry point: one function per paper table/figure plus the
-roofline/dry-run, pressure, fault-replay, kernel and simulator-perf benches.
+pressure, fault-replay, kernel and simulator-perf benches.
 
 Prints human-readable tables followed by a machine-readable
 ``name,value,derived`` CSV block.
@@ -15,12 +15,12 @@ pseudo-random streams, no global RNG), so the workers share nothing and
 the output — both the per-suite tables and the CSV block — is printed in
 the deterministic ``--only`` order regardless of completion order:
 ``--jobs 1`` and ``--jobs N`` produce identical suite output for every
-deterministic suite.  (The wall-clock-measuring suites — ``simperf``,
-``perf`` — print timings, which naturally vary run to run and are skewed
-when siblings saturate the CPU; run those with ``--jobs 1`` when the
-numbers matter.)  Workers run JAX on the CPU only: an accelerator belongs
-to one process, so the suites that run on the device (``DEVICE_SUITES``)
-stay in the parent.
+deterministic suite.  (The wall-clock-measuring suite ``simperf`` prints
+timings, which naturally vary run to run and are skewed when siblings
+saturate the CPU; run it with ``--jobs 1`` when the numbers matter.)
+Workers run JAX on the CPU only: an accelerator belongs to one process,
+so the suites that run on the device (``DEVICE_SUITES``) stay in the
+parent.
 
 Profiling: ``--profile`` wraps the selected suites in cProfile and prints
 the top-20 cumulative entries afterwards, so perf work starts from data.
@@ -49,7 +49,7 @@ DEVICE_SUITES = {"kernels"}
 def _suite_table() -> Dict:
     from benchmarks import (faults_bench, fleet_bench, kernel_bench,
                             paper_figures, perf_bench, pressure_bench,
-                            roofline_bench, serving_bench)
+                            serving_bench)
 
     return {
         "table3": paper_figures.table3_characterize,
@@ -69,9 +69,6 @@ def _suite_table() -> Dict:
         "serving": serving_bench.serving_curve,
         "faults": faults_bench.fault_injection,
         "fleet": fleet_bench.fleet_serving,
-        "roofline": roofline_bench.roofline_table,
-        "dryrun": roofline_bench.multi_pod_check,
-        "perf": roofline_bench.perf_deltas,
         "simperf": perf_bench.perf_suite,
     }
 
@@ -219,9 +216,9 @@ def write_run_report(path: str, csv_rows: List[str],
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
-                    help="comma list: fig7a,fig7b,fig8,fig9,fig10,table3,"
-                         "overhead,roofline,pressure,fault,mix,gc,"
-                         "gc_policies,serving,faults,fleet,kernels,simperf")
+                    help="comma list: table3,fig7a,fig7b,fig8,fig9,fig10,"
+                         "overhead,kernels,latmodel,pressure,fault,mix,gc,"
+                         "gc_policies,serving,faults,fleet,simperf")
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized configurations for smoke-aware suites "
                          "(mix, gc, gc_policies, serving): tiny sweeps "

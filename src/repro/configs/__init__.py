@@ -1,8 +1,7 @@
 """Assigned architecture configurations (``--arch <id>``).
 
 One module per architecture with the exact published config; ``get(name)``
-returns the ArchConfig, ``ARCHS`` lists all ids.  Input-shape sets are in
-:mod:`repro.configs.shapes`.
+returns the ArchConfig, ``ARCHS`` lists all ids.
 """
 from __future__ import annotations
 

@@ -1,6 +1,7 @@
 """llama2-7b — the paper's own evaluated model (§5.4: INT8 LLaMA2-7B
 inference and training via llama2.c [308]).  Not part of the assigned
-10-arch pool; selectable for dry-runs and the simulator workloads."""
+10-arch pool; selectable through ``configs.get`` and used by the simulator
+workloads."""
 from repro.models.config import ArchConfig
 
 CONFIG = ArchConfig(

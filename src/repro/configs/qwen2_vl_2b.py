@@ -1,6 +1,6 @@
 """qwen2-vl-2b [vlm]: 28L d_model=1536 12H (GQA kv=2) d_ff=8960
 vocab=151936 — M-RoPE, dynamic resolution; vision frontend is a STUB
-(input_specs provides precomputed patch embeddings).  [arXiv:2409.12191; hf]"""
+(the model takes precomputed patch embeddings).  [arXiv:2409.12191; hf]"""
 from repro.models.config import ArchConfig
 
 CONFIG = ArchConfig(

@@ -1,6 +1,6 @@
 """seamless-m4t-medium [audio]: 12L d_model=1024 16H (GQA kv=16) d_ff=4096
 vocab=256206 — encoder-decoder; the speech frontend is a STUB
-(input_specs provides precomputed frame embeddings).  [arXiv:2308.11596; hf]"""
+(the model takes precomputed frame embeddings).  [arXiv:2308.11596; hf]"""
 from repro.models.config import ArchConfig
 
 CONFIG = ArchConfig(

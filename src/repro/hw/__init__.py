@@ -1,5 +1,4 @@
-"""Hardware models: simulated SSD (paper Table 2) and target TPU v5e constants."""
+"""Hardware model: the simulated SSD (paper Table 2)."""
 from repro.hw.ssd_spec import SSDSpec, DEFAULT_SSD
-from repro.hw.tpu_spec import TPUSpec, TPU_V5E
 
-__all__ = ["SSDSpec", "DEFAULT_SSD", "TPUSpec", "TPU_V5E"]
+__all__ = ["SSDSpec", "DEFAULT_SSD"]

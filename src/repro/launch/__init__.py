@@ -1,1 +1,2 @@
-"""Launch layer: production mesh, sharding plans, step builders, dry-run."""
+"""Launch layer: meshes, sharding plans, step builders, train and serve
+drivers."""

@@ -1,14 +1,14 @@
 """Sharding plans: parameter / optimizer / cache / batch PartitionSpecs.
 
-The baseline plan is name-rule-driven 2D sharding: tensor-parallel over
+The plan is name-rule-driven 2D sharding: tensor-parallel over
 "model" (attention heads, FFN columns, expert dim, vocab-free embedding
 feature dim) and FSDP-style weight sharding over "data" (+"pod").  Every
 axis assignment is divisibility-checked against the mesh and dropped when
 it does not divide (e.g. 2-head KV caches on a 16-way model axis shard the
-sequence dimension instead) — so every (arch x shape x mesh) cell lowers.
-
-The Conduit-for-TPU scheduler (repro.distributed.scheduler) perturbs this
-plan during the §Perf hillclimb.
+sequence dimension instead), so every architecture lowers on any mesh.
+These plans are fixed: the name rules decide them, and nothing searches
+over them. The model's own sharding hints (``models/layers.py``) follow
+the active mesh with the same axis naming rule, ``mesh_axes``.
 """
 from __future__ import annotations
 
@@ -16,13 +16,10 @@ import functools
 from typing import Any, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.mesh import mesh_axes
 from repro.models.config import ArchConfig
-from repro.models.layers import kv_order
+from repro.models.layers import kv_order, mesh_axes
 
 # column-parallel leaves (shard last dim over "model", -2 over data/FSDP)
 _COL = {"wq", "wk", "wv", "w1", "w3", "w_uq", "w_uk", "w_uv", "w_q",
@@ -160,16 +157,3 @@ def batch_spec(shape, mesh) -> P:
     spec = [data if data else None] + [None] * (len(shape) - 1)
     return _fit(mesh, shape, spec)
 
-
-def embeds_spec(shape, mesh) -> P:
-    data, model = mesh_axes(mesh)
-    spec = [data if data else None] + [None] * (len(shape) - 2) + [model]
-    return _fit(mesh, shape, spec)
-
-
-def to_sds(tree_shapes: Any, tree_specs: Any, mesh) -> Any:
-    """ShapeDtypeStructs with attached NamedShardings (no allocation)."""
-    return jax.tree_util.tree_map(
-        lambda leaf, spec: jax.ShapeDtypeStruct(
-            leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, spec)),
-        tree_shapes, tree_specs)

@@ -1,47 +1,25 @@
 """Step functions: train_step / prefill_step / serve_step per architecture.
 
-These are the functions the dry-run lowers and the drivers execute.  The
-train step is a full optimization step (loss, backward, AdamW with the
-arch's schedule); the serve step is one decode iteration against the KV
-cache / recurrent state.
+These are the functions the drivers and the benchmark jit.  The train
+step is a full optimization step (loss, backward, AdamW with the arch's
+schedule); the serve step is one decode iteration against the KV cache /
+recurrent state.
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Callable, Dict, Optional
+from typing import Callable
 
 import jax
-import jax.numpy as jnp
 
 from repro.models import model as M
 from repro.models.config import ArchConfig
 from repro.optim import adamw_update, make_schedule
 
 
-def extra_inputs(cfg: ArchConfig, batch: int, seq: int) -> Dict[str, Any]:
-    """Modality-frontend STUBS (per assignment): shapes of the precomputed
-    frame/patch embeddings and auxiliary position streams."""
-    extras: Dict[str, Any] = {}
-    if cfg.frontend == "vision_patches":
-        n_patch = 64                       # one low-res image per sequence
-        extras["extra_embeds"] = jax.ShapeDtypeStruct(
-            (batch, n_patch, cfg.d_model), jnp.bfloat16)
-        extras["pos3"] = jax.ShapeDtypeStruct(
-            (3, batch, seq + n_patch), jnp.int32)
-    elif cfg.frontend == "audio_frames":
-        n_frames = max(8, seq // 4)        # encoder frames per utterance
-        extras["enc_feats"] = jax.ShapeDtypeStruct(
-            (batch, n_frames, cfg.d_model), jnp.bfloat16)
-    return extras
-
-
 def build_train_step(cfg: ArchConfig, total_steps: int = 10_000,
-                     base_lr: float = 3e-4,
-                     microbatches: int = 1) -> Callable:
-    """Full optimization step.  ``microbatches > 1`` accumulates gradients
-    over batch slices (scan) — smaller activation peak and per-microbatch
-    gradient reduction that XLA can overlap with the next microbatch's
-    compute (the ConduitScheduler's `micro4` plan)."""
+                     base_lr: float = 3e-4) -> Callable:
+    """Full optimization step: loss and gradients of the whole batch,
+    then AdamW at the schedule's learning rate."""
     schedule = make_schedule(cfg.schedule, base_lr, total_steps)
 
     def loss_of(p, batch):
@@ -53,33 +31,7 @@ def build_train_step(cfg: ArchConfig, total_steps: int = 10_000,
 
     def train_step(params, opt_state, batch):
         step = opt_state.step
-        if microbatches == 1:
-            loss, grads = jax.value_and_grad(loss_of)(params, batch)
-        else:
-            b = batch["tokens"].shape[0]
-            assert b % microbatches == 0, (b, microbatches)
-            mb = b // microbatches
-
-            def slice_mb(i):
-                return {k: jax.lax.dynamic_slice_in_dim(v, i * mb, mb, 0)
-                        for k, v in batch.items()
-                        if k in ("tokens", "labels", "extra_embeds")} | \
-                    {k: v for k, v in batch.items()
-                     if k not in ("tokens", "labels", "extra_embeds")}
-
-            def body(carry, i):
-                loss_acc, grads_acc = carry
-                l, g = jax.value_and_grad(loss_of)(params, slice_mb(i))
-                grads_acc = jax.tree_util.tree_map(
-                    lambda a, b_: a + b_.astype(a.dtype), grads_acc, g)
-                return (loss_acc + l, grads_acc), None
-
-            zeros = jax.tree_util.tree_map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            (loss, grads), _ = jax.lax.scan(
-                body, (jnp.float32(0), zeros), jnp.arange(microbatches))
-            loss = loss / microbatches
-            grads = jax.tree_util.tree_map(lambda g: g / microbatches, grads)
+        loss, grads = jax.value_and_grad(loss_of)(params, batch)
         lr = schedule(step)
         new_params, new_state, metrics = adamw_update(
             params, grads, opt_state, lr)

@@ -3,8 +3,8 @@
 CPU-scale end-to-end training of any ``--arch`` (reduced config by default)
 with checkpoint/restart, deterministic data, straggler monitoring, and
 optional fault injection.  Given a ``mesh``, ``train()`` places params,
-optimizer state and batches with the ``launch/sharding.py`` rules (the ones
-the dry run compiles) and runs every step under that mesh.
+optimizer state and batches with the ``launch/sharding.py`` rules and runs
+every step under that mesh.
 
   PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
       --steps 100 --batch 8 --seq 64 --ckpt-dir /tmp/ck
@@ -29,10 +29,8 @@ from repro.launch import sharding as SH
 from repro.launch.cache import use_compile_cache
 from repro.launch.elastic import (SimulatedFailure, StragglerMonitor,
                                   run_elastic)
-from repro.launch.mesh import mesh_axes
 from repro.launch.specs import opt_shapes, params_shapes
 from repro.launch.steps import build_train_step
-from repro.models import layers as L
 from repro.models import model as M
 from repro.models.config import ArchConfig
 from repro.optim.adamw import adamw_init
@@ -62,17 +60,10 @@ def make_state(cfg, seed: int, shardings: Optional[dict] = None):
 
 @contextlib.contextmanager
 def on_mesh(mesh):
-    """Bind the model's sharding hints to ``mesh`` for the duration."""
-    if mesh is None:
+    """Bind the model's sharding hints to ``mesh`` for the duration:
+    ``jax.set_mesh(mesh)``, or nothing for None."""
+    with contextlib.nullcontext() if mesh is None else jax.set_mesh(mesh):
         yield
-        return
-    prev_axes = (L.data_axes(), L.model_axis())
-    L.set_mesh_axes(*mesh_axes(mesh))
-    try:
-        with jax.set_mesh(mesh):
-            yield
-    finally:
-        L.set_mesh_axes(*prev_axes)
 
 
 def jit_train_step(cfg: ArchConfig, total_steps: int, base_lr: float,

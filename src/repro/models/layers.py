@@ -7,8 +7,9 @@ Everything is a pure function over a params dict so layer stacks can be
 
   batch/tokens -> ("pod","data")     heads / ffn / experts -> "model"
 
-GSPMD propagates the rest and inserts the collectives the roofline
-analysis measures.
+The hints follow the mesh made active with ``jax.set_mesh`` (``mesh_axes``
+names its axes) and are no-ops outside one. GSPMD propagates the rest and
+inserts the collectives.
 """
 from __future__ import annotations
 
@@ -26,23 +27,23 @@ from repro.models.config import ArchConfig
 
 Params = Dict[str, Any]
 
-# Mesh axis names used by the sharding hints; the launcher rebinds these to
-# the active mesh (("pod","data") on the multi-pod mesh, ("data",) on the
-# single-pod mesh, () when running unsharded smoke tests on CPU).
-_MESH_AXES = {"data": (), "model": None}
 
-
-def set_mesh_axes(data_axes: Tuple[str, ...], model_axis: Optional[str]):
-    _MESH_AXES["data"] = tuple(data_axes)
-    _MESH_AXES["model"] = model_axis
+def mesh_axes(mesh) -> Tuple[Tuple[str, ...], Optional[str]]:
+    """(data axes, model axis) of ``mesh``: "pod" and "data" carry the
+    batch, "model" the heads and features; ((), None) for an empty mesh."""
+    names = mesh.axis_names
+    return (tuple(a for a in names if a in ("pod", "data")),
+            "model" if "model" in names else None)
 
 
 def data_axes() -> Tuple[str, ...]:
-    return _MESH_AXES["data"]
+    """The active mesh's (``jax.set_mesh``) data axes; () outside one."""
+    return mesh_axes(jax.sharding.get_abstract_mesh())[0]
 
 
 def model_axis() -> Optional[str]:
-    return _MESH_AXES["model"]
+    """The active mesh's model axis; None outside one."""
+    return mesh_axes(jax.sharding.get_abstract_mesh())[1]
 
 
 def _maybe_shard(x, spec):

@@ -2,8 +2,8 @@
 
 Layers with identical block kinds are grouped into *segments*; each segment
 stacks its parameters along a leading layer axis and executes under
-``jax.lax.scan`` — HLO size is O(#segments), not O(depth), which keeps the
-236B-parameter dry-run compiles fast.  Heterogeneous patterns (zamba2's
+``jax.lax.scan`` — HLO size is O(#segments), not O(depth), which keeps
+compiles of deep models fast.  Heterogeneous patterns (zamba2's
 mamba blocks + shared attention, xLSTM's mlstm/slstm alternation) become
 short segment lists.  ``jax.checkpoint`` wraps the block body when
 ``cfg.remat`` (activation rematerialization for training).

@@ -1,7 +1,7 @@
 import os
 
-# Smoke tests and benches must see 1 CPU device (the dry-run sets its own
-# flag before any import) — never force the 512-device fake platform here.
+# Smoke tests and benches run on the CPU; a test that needs several devices
+# or a described TPU makes them itself.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax  # noqa: E402

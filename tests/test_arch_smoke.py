@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 from repro import configs
-from repro.configs.shapes import SHAPES, applicable
 from repro.launch.steps import build_serve_step, build_train_step
 from repro.models import model as M
 from repro.optim.adamw import adamw_init
@@ -99,19 +98,3 @@ def test_moe_configs_exact():
     assert ds.n_shared_experts == 2
     assert ds.mla and ds.kv_lora_rank == 512
 
-
-def test_shape_applicability_matrix():
-    """40 cells total; long_500k applies only to sub-quadratic archs."""
-    total = runnable = 0
-    for arch in configs.ARCHS:
-        cfg = configs.get(arch)
-        for shape in SHAPES:
-            total += 1
-            ok, reason = applicable(cfg, shape)
-            if ok:
-                runnable += 1
-            else:
-                assert shape == "long_500k" and not cfg.sub_quadratic
-                assert "sub-quadratic" in reason or "full-attention" in reason
-    assert total == 40
-    assert runnable == 32   # 8 full-attention archs skip long_500k

@@ -1,7 +1,7 @@
 """Launch-layer laws on the CPU: the serving driver's accounting, jitted
 init, training on a mesh, the compile-cache location, sharding hints and
-cache specs, and chip_smoke.py's cache-consistency check at a reduced
-config."""
+the mesh axes they follow, the sharding rules and cache specs, and
+chip_smoke.py's cache-consistency check at a reduced config."""
 import dataclasses
 import sys
 from pathlib import Path
@@ -10,8 +10,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import NamedSharding
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
+from jax.tree_util import DictKey
 
 from repro import configs
 from repro.launch import cache as C
@@ -19,7 +20,7 @@ from repro.launch import sharding as SH
 from repro.launch.mesh import make_mesh
 from repro.launch.serve import serve
 from repro.launch.specs import cache_shapes
-from repro.launch.train import train
+from repro.launch.train import on_mesh, train
 from repro.models import layers as L
 from repro.models import model as M
 
@@ -94,6 +95,97 @@ def test_sharding_hints_apply_only_under_a_mesh():
     mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     with jax.set_mesh(mesh), pytest.raises(Exception, match="nowhere"):
         jax.jit(lambda y: L._maybe_shard(y, P("nowhere")))(x)
+
+
+def _one_device_mesh(axes):
+    return make_mesh((1,) * len(axes), axes, devices=jax.devices()[:1])
+
+
+# (mesh axes, the hints' data axes, the hints' model axis)
+MESH_AXES = [(("data", "model"), ("data",), "model"),
+             (("pod", "data", "model"), ("pod", "data"), "model"),
+             (("data",), ("data",), None),
+             (("model",), (), "model")]
+
+
+@pytest.mark.parametrize("axes, data, model", MESH_AXES,
+                         ids=["-".join(a) for a, _, _ in MESH_AXES])
+def test_hints_follow_the_active_mesh_axes(axes, data, model):
+    """Under ``on_mesh`` the hints' axes are ``mesh_axes`` of the mesh,
+    eagerly and while a jitted function traces; none outside it."""
+    mesh = _one_device_mesh(axes)
+    assert L.mesh_axes(mesh) == (data, model)
+    traced = []
+
+    def f(x):
+        traced.append((L.data_axes(), L.model_axis()))
+        return x
+
+    with on_mesh(mesh):
+        assert (L.data_axes(), L.model_axis()) == (data, model)
+        jax.jit(f)(jnp.ones(4))
+    assert traced == [(data, model)]
+    assert (L.data_axes(), L.model_axis()) == ((), None)
+
+
+def test_a_bare_set_mesh_binds_the_hints():
+    """``jax.set_mesh`` alone binds the hints: a [B, S, D] activation is
+    constrained to batch over data and features over model."""
+    x = jnp.ones((2, 4, 8))
+    assert not jax.make_jaxpr(L.shard_tokens)(x).eqns
+    with jax.set_mesh(_one_device_mesh(("data", "model"))):
+        assert (L.data_axes(), L.model_axis()) == (("data",), "model")
+        [eqn] = jax.make_jaxpr(L.shard_tokens)(x).eqns
+    assert eqn.primitive.name == "sharding_constraint"
+    assert eqn.params["sharding"].spec == P(("data",), None, "model")
+
+
+def test_leaving_a_nested_mesh_restores_the_outer_axes():
+    outer = _one_device_mesh(("pod", "data", "model"))
+    with on_mesh(outer):
+        with on_mesh(_one_device_mesh(("data",))):
+            assert (L.data_axes(), L.model_axis()) == (("data",), None)
+        assert (L.data_axes(), L.model_axis()) == (("pod", "data"), "model")
+        with on_mesh(None):               # no mesh given: the outer stays
+            assert L.data_axes() == ("pod", "data")
+    assert (L.data_axes(), L.model_axis()) == ((), None)
+
+
+def test_mesh_is_function_not_constant():
+    import importlib
+    import repro.launch.mesh as mesh_mod
+    importlib.reload(mesh_mod)   # importing must not touch device state
+    assert callable(mesh_mod.make_mesh)
+
+
+def test_fit_drops_nondivisible_axes():
+    dev = np.array(jax.devices()[:1]).reshape(1, 1)
+    mesh = Mesh(dev, ("data", "model"))
+    # axis size 1 always divides
+    spec = SH._fit(mesh, (7, 13), ["data", "model"])
+    assert spec == P("data", "model")
+
+
+def test_param_spec_rules():
+    dev = np.array(jax.devices()[:1]).reshape(1, 1)
+    mesh = Mesh(dev, ("data", "model"))
+    # column-parallel
+    spec = SH.param_spec_for((DictKey("attn"), DictKey("wq")),
+                             (4, 64, 64), mesh, ("data",), "model")
+    assert spec[-1] == "model"
+    # row-parallel
+    spec = SH.param_spec_for((DictKey("mlp"), DictKey("w2")),
+                             (4, 64, 64), mesh, ("data",), "model")
+    assert spec[-2] == "model"
+    # experts: EP over model at dim -3
+    spec = SH.param_spec_for((DictKey("moe"), DictKey("experts"),
+                              DictKey("w1")), (2, 4, 8, 8), mesh,
+                             ("data",), "model")
+    assert spec[1] == "model"
+    # norms replicate
+    spec = SH.param_spec_for((DictKey("ln1"),), (64,), mesh,
+                             ("data",), "model")
+    assert all(e is None for e in spec)
 
 
 @pytest.mark.parametrize("batch", [1, 3, 8])

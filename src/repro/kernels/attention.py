@@ -19,6 +19,11 @@ rounded to q's dtype: then the backward's rows of dS miss summing to zero by
 that rounding, and the miss lands on what the softmax cancels, the gradient
 of a key bias (zero in exact arithmetic), about twice the noise the jnp
 attention leaves there.
+
+``decode_gqa_attention`` is the decode step's (``models/layers.
+gqa_attention`` on the TPU): one query position per sequence against the
+stacked K/V cache in its ``shbd`` order, read in place, and only its filled
+prefix (``kv_blocks_read``).
 """
 from __future__ import annotations
 
@@ -194,3 +199,130 @@ def _flash_mha_bwd(causal, block, res, do):
 
 
 flash_mha.defvjp(_flash_mha_fwd, _flash_mha_bwd)
+
+
+# the decode kernel's positions a grid step: a v5e at the benchmark's
+# shapes picks it (PERF.md section 6, PR 18)
+DECODE_BLOCK = 256
+
+
+def kv_blocks_read(index, block: int):
+    """Blocks of ``block`` positions a decode step writing at ``index``
+    reads: those holding positions 0..index, ceil((index + 1) / block)."""
+    return index // block + 1
+
+
+def _decode_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+                   acc_ref, *, block: int, scale: float):
+    """One KV head (grid axis 0) and one group of G sequences (axis 1)
+    over one block of positions (axis 2).
+
+    q_ref [G, r*dh]: the head's r query heads side by side; k_ref and v_ref
+    [block, G, dh]. The r*G query rows (head-major) meet the block's
+    block*G key rows in one product; a row keeps the columns of its own
+    sequence (``col % G == row % G``), so the softmax and the PV product see
+    each sequence's keys alone. Blocks past the one holding ``index`` are
+    neither fetched (the index map repeats that block) nor computed; in
+    that block the positions past ``index`` are masked, and their values
+    zeroed, so that no unfilled row (zeros, a former batch's, or NaN)
+    reaches the output."""
+    j = pl.program_id(2)
+    index = meta_ref[1]
+    last = kv_blocks_read(index, block) - 1
+    g, width = q_ref.shape
+    dh = k_ref.shape[-1]
+    r = width // dh
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def _step(partial: bool):
+        q = q_ref[...]
+        q = jnp.concatenate([q[:, i * dh:(i + 1) * dh] for i in range(r)])
+        k = k_ref[...].reshape(block * g, dh)       # row p * G + sequence
+        v = v_ref[...].reshape(block * g, dh)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [r*G, block*G]
+        # G is 8 or 16: bit masks and shifts stand for % G and // G
+        iota = functools.partial(jax.lax.broadcasted_iota, jnp.int32, s.shape)
+        keep = (iota(0) & (g - 1)) == (iota(1) & (g - 1))
+        if partial:
+            shift = g.bit_length() - 1
+            keep &= j * block + (iota(1) >> shift) <= index
+            pos = j * block + (jax.lax.broadcasted_iota(
+                jnp.int32, (block * g, 1), 0) >> shift)
+            v = jnp.where(pos <= index, v, jnp.zeros_like(v))
+        s = jnp.where(keep, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    pl.when(j < last)(lambda: _step(False))
+    pl.when(j == last)(lambda: _step(True))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        out = acc_ref[...] / l_ref[...]              # [r*G, dh]
+        o_ref[...] = jnp.concatenate(
+            [out[i * g:(i + 1) * g] for i in range(r)], axis=1
+        ).astype(o_ref.dtype)
+
+
+def decode_gqa_attention(q, k, v, layer, index, block: int = DECODE_BLOCK,
+                         interpret: bool = False):
+    """Attention of one query position per sequence over layer ``layer``
+    of the stacked K/V cache, positions 0..``index``.
+
+    q [B, H, dh], B a multiple of 8; k, v [L, S, Hkv, B, dh]
+    (``models/layers.kv_order``'s ``shbd``), read in place: each grid step
+    fetches one KV head's ``block`` positions of 16 sequences (8 where 16
+    do not divide B), and only the ``kv_blocks_read(index, block)`` blocks
+    that hold the filled prefix. The H = Hkv * r query heads are grouped by
+    KV head as ``gqa_attention`` groups them. Logits and the online softmax
+    in float32, both products in the cache's dtype with float32
+    accumulation, scaled by 1/sqrt(dh). Returns [B, H, dh]."""
+    b, h, dh = q.shape
+    _, s, hkv, _, _ = k.shape
+    r = h // hkv
+    g = 16 if b % 16 == 0 else 8
+    block = min(block, s)
+    meta = jnp.stack([jnp.asarray(layer, jnp.int32),
+                      jnp.asarray(index, jnp.int32)])
+
+    def kv_map(hi, gi, j, meta):
+        return meta[0], jnp.minimum(
+            j, kv_blocks_read(meta[1], block) - 1), hi, gi, 0
+
+    kv_spec = pl.BlockSpec((None, block, None, g, dh), kv_map)
+    q_spec = pl.BlockSpec((g, r * dh), lambda hi, gi, j, meta: (gi, hi))
+    itemsize = k.dtype.itemsize
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block=block,
+                          scale=1.0 / np.sqrt(dh)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(hkv, b // g, pl.cdiv(s, block)),
+            in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((r * g, 1), jnp.float32),
+                            pltpu.VMEM((r * g, 1), jnp.float32),
+                            pltpu.VMEM((r * g, dh), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h * dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="decode_gqa_attention",
+        cost_estimate=pl.CostEstimate(   # at a full cache
+            flops=4 * h * b * s * g * dh,
+            transcendentals=h * b * s * g,
+            bytes_accessed=2 * hkv * s * b * dh * itemsize
+            + 2 * b * h * dh * q.dtype.itemsize),
+        interpret=interpret,
+    )(meta, q.reshape(b, h * dh), k, v)
+    return out.reshape(b, h, dh)

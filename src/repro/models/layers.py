@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.attention import flash_mha
+from repro.kernels.attention import decode_gqa_attention, flash_mha
 from repro.models.config import ArchConfig
 
 Params = Dict[str, Any]
@@ -321,6 +321,44 @@ def kv_carried(cfg: ArchConfig, batch: int) -> bool:
     return kv_order(cfg, batch) != "bshd"
 
 
+def _decode_fits(q, order: str) -> bool:
+    """The shapes ``decode_gqa_attention`` takes: one query position per
+    sequence over a cache in the ``shbd`` order, heads a whole number of
+    128-lane rows wide, and a batch of whole 8-row groups, outside a mesh
+    (a ``pallas_call`` has no partitioning rule)."""
+    b, s, _, dh = q.shape
+    return (s == 1 and order == "shbd" and dh % 128 == 0 and b % 8 == 0
+            and jax.sharding.get_abstract_mesh().empty)
+
+
+def _cached_attention(q, ck, cv, layer, idx, order: str):
+    """q [B,S,H,dh] at positions ``idx``.. over layer ``layer`` of the
+    stacked cache (``kv_order``), in jnp: the layer is sliced out whole and
+    attended up to each query's position."""
+    b, s, h, dh = q.shape
+    kl = jax.lax.dynamic_index_in_dim(ck, layer, keepdims=False)
+    vl = jax.lax.dynamic_index_in_dim(cv, layer, keepdims=False)
+    kv = order.replace("s", "k")      # the cache's positions are keys
+    # GQA without materializing repeated K/V: fold the group dim into q
+    # instead of jnp.repeat-ing the cache n_rep times — it is read once.
+    n_kv = kl.shape[order.index("h")]
+    qg = q.reshape(b, s, n_kv, h // n_kv, dh)
+    smax = kl.shape[order.index("s")]
+    kpos = jax.lax.broadcasted_iota(jnp.int32, (s, smax), 1)
+    qpos = idx + jax.lax.broadcasted_iota(jnp.int32, (s, smax), 0)
+    mask = kpos <= qpos          # causal over the filled prefix
+    # contract the cache in bf16 with fp32 accumulation — upcasting kl/vl
+    # with astype would materialize the layer's cache in fp32 (2x its
+    # bytes) before the einsum.
+    logits = jnp.einsum(f"bqhrd,{kv}->bhrqk", qg, kl,
+                        preferred_element_type=jnp.float32) / np.sqrt(dh)
+    logits = jnp.where(mask[None, None, None], logits, -1e30)
+    probs = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum(f"bhrqk,{kv}->bqhrd", probs.astype(q.dtype), vl,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, s, h, dh).astype(q.dtype)
+
+
 def gqa_attention(p: Params, cfg: ArchConfig, x, positions,
                   cache: Optional[Dict] = None, pos3=None,
                   causal: bool = True,
@@ -330,8 +368,10 @@ def gqa_attention(p: Params, cfg: ArchConfig, x, positions,
 
     ``cache``: {"k","v": stacked caches, [L] + one layer in
     ``kv_order(cfg, B)``; "index", "layer": scalars}. The new K/V rows are
-    written at ``index`` of layer ``layer`` in place (named ``kv_write``);
-    the layer is read whole and attended up to each query's position.
+    written at ``index`` of layer ``layer`` in place (named ``kv_write``),
+    then attended up to each query's position: on the TPU, where
+    ``_decode_fits``, by ``decode_gqa_attention`` reading the filled prefix
+    of the stacks in place; else by ``_cached_attention``.
     Returns (out, {"k","v"}: the updated stacks).
     """
     b, s, d = x.shape
@@ -371,27 +411,14 @@ def gqa_attention(p: Params, cfg: ArchConfig, x, positions,
             cv = jax.lax.dynamic_update_slice(cache["v"],
                                               v.transpose(perm)[None], at)
         new_cache = {"k": ck, "v": cv}
-        kl = jax.lax.dynamic_index_in_dim(ck, layer, keepdims=False)
-        vl = jax.lax.dynamic_index_in_dim(cv, layer, keepdims=False)
-        kv = order.replace("s", "k")      # the cache's positions are keys
-        # GQA without materializing repeated K/V (perf iteration D1,
-        # EXPERIMENTS.md §Perf): fold the group dim into q instead of
-        # jnp.repeat-ing the cache n_rep times — the cache is read once.
-        qg = q.reshape(b, s, cfg.n_kv_heads, n_rep, dh)
-        smax = kl.shape[order.index("s")]
-        kpos = jax.lax.broadcasted_iota(jnp.int32, (s, smax), 1)
-        qpos = idx + jax.lax.broadcasted_iota(jnp.int32, (s, smax), 0)
-        mask = kpos <= qpos          # causal over the filled prefix
-        # perf iteration D3: contract the cache in bf16 with fp32
-        # accumulation — upcasting kl/vl with astype would materialize the
-        # layer's cache in fp32 (2x its bytes) before the einsum.
-        logits = jnp.einsum(f"bqhrd,{kv}->bhrqk", qg, kl,
-                            preferred_element_type=jnp.float32) / np.sqrt(dh)
-        logits = jnp.where(mask[None, None, None], logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum(f"bhrqk,{kv}->bqhrd", probs.astype(x.dtype), vl,
-                         preferred_element_type=jnp.float32)
-        out = out.reshape(b, s, cfg.n_heads, dh).astype(x.dtype)
+        if _decode_fits(q, order):
+            out = jax.lax.platform_dependent(
+                q, ck, cv, layer, idx,
+                tpu=lambda q, ck, cv, layer, idx: decode_gqa_attention(
+                    q[:, 0], ck, cv, layer, idx)[:, None],
+                default=lambda *a: _cached_attention(*a, order))
+        else:
+            out = _cached_attention(q, ck, cv, layer, idx, order)
     else:
         kk = _repeat_kv(k, n_rep)
         vv = _repeat_kv(v, n_rep)

@@ -26,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import configs
 from repro.kernels import ops
+from repro.kernels.attention import decode_gqa_attention
 from repro.launch.mesh import make_mesh
 from repro.launch.specs import cache_shapes, opt_shapes, params_shapes
 from repro.launch.steps import build_prefill_step, build_serve_step
@@ -96,6 +97,12 @@ KERNELS = {
     "attention_32k_full": (
         lambda q, k, v: ops.flash_attention(q, k, v, causal=False),
         [((8, 32768, 128), BF16)] * 3),
+    # the decode step's kernel at the benchmark's shapes: one layer of the
+    # stacked qwen3-4b K/V cache at batch 16 and max_seq 1280
+    "decode_gqa_attention": (
+        decode_gqa_attention,
+        [((16, 32, 128), BF16)] + [((36, 1280, 8, 16, 128), BF16)] * 2
+        + [((), I32)] * 2),
 }
 
 
@@ -136,6 +143,11 @@ def test_qwen3_4b_serving_step_fits_one_v5e(step, one_chip):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+_TPU_CUSTOM_CALL = re.compile(
+    r'^\s*(?:ROOT )?%?([\w.\-]+) = .*custom_call_target="tpu_custom_call"',
+    re.M)
+
+
 # the shapes a copy or an asynchronous copy's start produces
 _COPIED = re.compile(r"^\s*(?:ROOT )?%?\S+ = (.*?)[)}] copy(?:-start)?\(",
                      re.M)
@@ -156,6 +168,21 @@ def test_qwen3_4b_decode_keeps_the_kv_cache_in_place(batch, one_chip):
     assert not _copied_stacks(compiled, stacks)
     kv_bytes = sum(a.size * a.dtype.itemsize for a in stacks)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.01 * kv_bytes
+
+
+def test_qwen3_4b_decode_reads_the_cache_through_the_kernel(one_chip):
+    """The benchmark's decode step (batch 16, max_seq 1280): its attention
+    is ``decode_gqa_attention``, once in the layer scan's body (so 36
+    times a step), bucketed as ``attention``; it reads the carried stacks
+    themselves, so no whole layer of K or V is sliced or copied out."""
+    compiled, stacks = _serving_step("decode", 16, 256, 1280, one_chip)
+    text = compiled.as_text()
+    calls = _TPU_CUSTOM_CALL.findall(text)
+    assert len(calls) == 1 and calls[0].startswith("decode_gqa_attention")
+    assert op_scopes(text)[calls[0]] == "attention"
+    assert "[36,1280,8,16,128]" in text
+    assert not re.search(r"\[(?:1,)?1280,8,16,128\]", text)
+    assert not _copied_stacks(compiled, stacks)
 
 
 # (arch, layers, batch) where ``L.kv_carried`` holds: grouped and full
@@ -256,11 +283,6 @@ def test_published_stablelm_train_step_fits_a_2x2_v5e(published_train_step):
     assert abs(m.argument_size_in_bytes / (state / 4) - 1) < 0.05
     assert m.argument_size_in_bytes + m.temp_size_in_bytes \
         < HBM_BYTES
-
-
-_TPU_CUSTOM_CALL = re.compile(
-    r'^\s*(?:ROOT )?%?([\w.\-]+) = .*custom_call_target="tpu_custom_call"',
-    re.M)
 
 
 def test_published_stablelm_train_step_runs_flash_attention(
